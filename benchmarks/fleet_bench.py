@@ -36,12 +36,13 @@ import time
 
 
 def _ensure_devices(n: int) -> None:
-    """Force ``n`` virtual CPU devices. Must precede any jax import."""
+    """Ask for ``n`` virtual devices on the CPU backend (the flag has no
+    effect on an accelerator, which brings its own devices). Must
+    precede any jax import."""
     if "jax" in sys.modules:
         raise SystemExit(
             "fleet_bench must set XLA_FLAGS before jax is imported; "
             "run it as its own process (benchmarks.run subprocesses it)")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -233,7 +234,10 @@ def main(argv=None) -> int:
         # 1-shard point. 1024 is also CI's quick smoke point, so the
         # committed curve carries a row its gate can compare against.
         grid = (1, 8, 64, 512, 1024, 2048, 10240)
-    rows = run_scaling(grid, (1, args.devices),
+    import jax
+
+    shards = sorted({1, min(args.devices, jax.device_count())})
+    rows = run_scaling(grid, tuple(shards),
                        chunks=1 if args.quick else 2,
                        lat_ticks=12 if args.quick else 24)
     rows += run_lifecycle(steps=36 if args.quick else 72)

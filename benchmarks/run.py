@@ -165,6 +165,18 @@ def main(argv=None) -> int:
             for r in fn(caps)]
 
     suites = {
+        # The child-process suites run first, while this process has not
+        # yet touched a device: an accelerator belongs to one process at
+        # a time, and the first in-process suite below takes it.
+        # Sharded-fleet scaling curve (virtual host devices on the CPU
+        # need XLA_FLAGS before jax is first imported).
+        "fleet": lambda: _fleet_rows(args.quick),
+        # chaos harness: guarded-tick overhead (5% CI budget) + keyed
+        # I/O fault smoke (saver retries, restore fallback)
+        "faults": lambda: _faults_rows(args.quick),
+        # static invariant audit alongside the perf rows (raises — and
+        # so records ERROR — on any violation)
+        "audit": lambda: _audit_rows(args.quick),
         "fig2": lambda: fig2_predict_time.run(
             n_grid=(64, 256) if args.quick else fig2_predict_time.N_GRID),
         "fig3": lambda: fig3_train_time.run(
@@ -224,19 +236,7 @@ def main(argv=None) -> int:
                 f"ratio={r['autotune_ratio']:.2f}x")
             for r in replay_bench.run_autotune(
                 ops=192 if args.quick else 384)],
-        # sharded-fleet scaling curve. Subprocessed: virtual host
-        # devices require XLA_FLAGS before jax's first import, and this
-        # module imported jax lines ago.
-        "fleet": lambda: _fleet_rows(args.quick),
-        # chaos harness: guarded-tick overhead (5% CI budget) + keyed
-        # I/O fault smoke (saver retries, restore fallback).
-        # Subprocessed like fleet to keep this process's jax state out
-        # of the measured child.
-        "faults": lambda: _faults_rows(args.quick),
         "roofline": lambda: roofline.run(mesh_filter=None),
-        # static invariant audit alongside the perf rows (subprocessed
-        # like fleet; raises — and so records ERROR — on any violation)
-        "audit": lambda: _audit_rows(args.quick),
     }
     only = set(args.only.split(",")) if args.only else set(suites)
 

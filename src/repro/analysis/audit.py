@@ -323,11 +323,7 @@ def check_retrace(target: AuditTarget, art: Artifact) -> dict:
         if "compil" in event:
             compile_events[0] += 1
 
-    try:
-        jax.monitoring.register_event_listener(_listener)
-        have_monitor = True
-    except Exception:  # pragma: no cover - older jax
-        have_monitor = False
+    jax.monitoring.register_event_listener(_listener)
 
     eng = art.build_engine()  # fresh engine: empty jit caches
     t = target
@@ -378,10 +374,9 @@ def check_retrace(target: AuditTarget, art: Artifact) -> dict:
                        "line": f"{key}: repeat lifecycle recompiled "
                                f"({first[key]} -> {second[key]})"})
     info = {"first_pass": first, "second_pass": second,
-            "budget": t.retrace_budget}
-    if have_monitor:
-        info["monitoring_compile_events"] = {
-            "first_pass": events_first, "second_pass": events_second}
+            "budget": t.retrace_budget,
+            "monitoring_compile_events": {
+                "first_pass": events_first, "second_pass": events_second}}
     return _result("retrace", target, "fail" if vs else "pass", vs, info)
 
 

@@ -47,18 +47,9 @@ from repro.core.measures.knn import KnnState
 
 BIG = 1e30
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:  # jax 0.4.x: experimental location, check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,8 @@ circular.)
 """
 from __future__ import annotations
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 
@@ -128,4 +130,23 @@ def check_window_occupancy(eng, state, n_of, wrap_of=None) -> None:
     eng._w_checked = True
 
 
-__all__ = ["scan_chunk", "ensure_room", "check_window_occupancy"]
+def restorable_shards(shards: int, n_sessions: int) -> int:
+    """Shard count a snapshot saved with ``shards`` restores onto here.
+
+    A snapshot from a sharded fleet restores wherever it lands (results
+    are bit-identical either way), but falling back to one device when
+    the saved shard count cannot be honoured is announced, never silent.
+    """
+    if shards <= 1:
+        return 1
+    if shards <= jax.device_count() and n_sessions % shards == 0:
+        return shards
+    warnings.warn(
+        f"snapshot was saved with {shards} shards; restoring onto one "
+        f"device ({jax.device_count()} visible, {n_sessions} sessions)",
+        RuntimeWarning, stacklevel=3)
+    return 1
+
+
+__all__ = ["scan_chunk", "ensure_room", "check_window_occupancy",
+           "restorable_shards"]

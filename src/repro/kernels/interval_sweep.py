@@ -32,7 +32,8 @@ def _kernel(xt_ref, a_ref, x_ref, ap_ref, kd_ref, kl_ref, live_ref,
     xt = xt_ref[...].astype(jnp.float32)  # (bm, p)
     x = x_ref[...].astype(jnp.float32)  # (bn, p)
     ab = jax.lax.dot_general(
-        xt, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xt, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST
     )
     a2 = jnp.sum(xt * xt, axis=1, keepdims=True)
     b2 = jnp.sum(x * x, axis=1, keepdims=True)

@@ -23,7 +23,8 @@ def _kernel(a_ref, b_ref, ya_ref, yb_ref, o_ref, *, inv2h2, bm, bn,
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     ab = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST
     )
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)

@@ -7,7 +7,9 @@ recomputed per tile (P flops/element — negligible next to the matmul).
 
 BlockSpec tiling: A tiles (bm, P) and B tiles (bn, P) stay resident in VMEM
 for a (bm, bn) output tile; P is zero-padded to a lane multiple (128) so the
-MXU operates on aligned shapes. Accumulation is f32 regardless of input dtype.
+MXU operates on aligned shapes. Accumulation is f32 regardless of input dtype,
+and the cross term asks for full f32 passes (``Precision.HIGHEST``) so the
+distances match the f32 reference rather than a one-pass bf16 product.
 """
 from __future__ import annotations
 
@@ -22,7 +24,8 @@ def _kernel(a_ref, b_ref, o_ref):
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     ab = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST
     )
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)

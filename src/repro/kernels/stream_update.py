@@ -59,15 +59,16 @@ def _kernel(scal_ref, x_ref, X_ref, y_ref, nd_ref, ny_ref,
     else:
         ab = jax.lax.dot_general(
             X, x, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (bn, 1)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)  # (bn, 1)
         a2 = jnp.sum(X * X, axis=1, keepdims=True)
         b2 = jnp.sum(x * x, axis=1, keepdims=True)  # (1, 1)
         d2 = a2 + b2 - 2.0 * ab
     d = jnp.sqrt(jnp.maximum(d2, 0.0))  # (bn, 1)
 
-    j = pl.program_id(0)
-    rows = (jax.lax.broadcasted_iota(jnp.float32, d.shape, 0)
-            + jnp.float32(block_n) * j.astype(jnp.float32))
+    # the TPU iota is integer-only: build row ids in int32, then cast
+    rows = (jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+            + block_n * pl.program_id(0)).astype(jnp.float32)
     # ring liveness: slot (head + i) % wrap is live for i < n. Row ids,
     # head, wrap and n are exact in f32 (cap << 2^24); the explicit
     # rows < wrap guard keeps slots beyond the ring modulus (and the

@@ -11,15 +11,8 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-agnostic jax.make_mesh (Auto axis types where supported).
-
-    jax >= 0.6 takes ``axis_types``; on 0.4.x the kwarg (and
-    ``jax.sharding.AxisType``) don't exist and Auto is the behaviour.
-    """
-    try:
-        kinds = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    kinds = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(shape, axes, axis_types=kinds)
 
 

@@ -254,18 +254,66 @@ def _emit_report(args, metrics, tracer, *, mode) -> None:
         print(f"[serve] trace -> {tracer.path}")
 
 
-def _serve_sessions(args) -> int:
-    """Multi-tenant online CP serving on the micro-batching engine."""
+def _device_line() -> str:
+    """Platform, device kind and count of the devices JAX serves on."""
     import jax
+
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
+
+
+def _check_steps(args) -> None:
+    """``--steps`` must be whole ``--chunk``s, more than one: the first
+    chunk is the compile warmup, and a shorter last chunk would compile
+    a new tick count inside the timed window."""
+    T, c = args.steps, args.chunk
+    if T <= c or T % c:
+        raise SystemExit(
+            f"--steps ({T}) must be a multiple of --chunk ({c}) and exceed "
+            "it: the first chunk is the compile warmup, and a shorter last "
+            "chunk would compile inside the clock")
+
+
+def _drive(args, drv, state, X, y, taus):
+    """Serve the (S, T) traffic through ``drv.observe_many`` in
+    ``--chunk``-tick dispatches. The first chunk is the compile warmup
+    and stays outside the clock; every chunk syncs on its p-values.
+    Returns the final state, the (S, T) p-values, the warmup seconds,
+    the timed seconds and the timed session-steps/s."""
+    import jax.numpy as jnp
     import numpy as np
+
+    S, T = y.shape
+    c = args.chunk
+    Xt, yt, tt = (jnp.swapaxes(jnp.asarray(a), 0, 1) for a in (X, y, taus))
+
+    def chunk(state, lo):
+        hi = lo + c
+        state, p = drv.observe_many(state, Xt[lo:hi], yt[lo:hi], tt[lo:hi])
+        return state, np.asarray(p).T
+
+    pvals = np.zeros((S, T), np.float32)
+    t0 = time.perf_counter()
+    state, pvals[:, :c] = chunk(state, 0)
+    warm = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for lo in range(c, T, c):
+        state, pvals[:, lo:lo + c] = chunk(state, lo)
+    dt = time.perf_counter() - t0
+    rate = S * (T - c) / dt
+    return state, pvals, warm, dt, rate
+
+
+def _run_sessions(args, metrics, tracer):
+    """Build the classification engine and serve ``--steps`` ticks of
+    seeded drift traffic. Returns everything a report or a check of the
+    served results needs."""
+    from types import SimpleNamespace
 
     from repro.serving import ServingEngine
 
-    metrics, tracer = _telemetry(args)
     S, T, dim = args.sessions, args.steps, args.dim
-    if T < 2:
-        raise SystemExit(
-            "--steps must be >= 2 (tick 0 is the compile warmup)")
+    _check_steps(args)
     _check_shards(args.shards, S)
     eng = ServingEngine(
         n_sessions=S, capacity=args.capacity, dim=dim, k=args.k,
@@ -274,31 +322,32 @@ def _serve_sessions(args) -> int:
     state = eng.init_state()
     metrics.gauge("serve_shards", mode="classification").set(args.shards)
     print(f"[serve] engine: {S} sessions x cap {args.capacity} "
-          f"(window={args.window}, k={args.k}, shards={args.shards})")
+          f"(window={args.window}, k={args.k}, shards={args.shards}, "
+          f"chunk={args.chunk}) on {_device_line()}")
 
     X, y, taus, drifted = _class_drift_traffic(args, S, T, dim)
     X, y, taus = _chaos_traffic(args, X, y, taus, mode="classification")
     drv, guard = _maybe_guard(args, eng, state, metrics, tracer)
-    pvals = np.zeros((S, T), np.float32)
-    state, _ = drv.observe(  # warmup tick 0 outside the clock (compile)
-        state, X[:, 0], y[:, 0], taus[:, 0])
-    pvals[:, 0] = np.nan
-    t0 = time.time()
-    for t in range(1, T):
-        state, p = drv.observe(state, X[:, t], y[:, t], taus[:, t])
-        pvals[:, t] = np.asarray(p)
-    dt = time.time() - t0
+    state, pvals, warm, dt, rate = _drive(args, drv, state, X, y, taus)
     metrics.gauge("serve_wall_s", mode="classification").set(dt)
     metrics.gauge("serve_session_steps_per_s", mode="classification").set(
-        S * (T - 1) / dt)
+        rate)
     eng.telemetry.drain()
     state = _drain_guard(guard, state)
-    _validity_metrics(pvals[:, 1:], drifted, args, engine="classification",
-                      metrics=metrics)
+    return SimpleNamespace(eng=eng, state=state, pvals=pvals, X=X, y=y,
+                           taus=taus, drifted=drifted, warmup_s=warm,
+                           steps_per_s=rate)
 
+
+def _serve_sessions(args) -> int:
+    """Multi-tenant online CP serving on the micro-batching engine."""
+    metrics, tracer = _telemetry(args)
+    run = _run_sessions(args, metrics, tracer)
+    _validity_metrics(run.pvals[:, 1:], run.drifted, args,
+                      engine="classification", metrics=metrics)
     rc = 0
     if args.snapshot_dir:
-        rc = _snapshot_roundtrip(args, state, eng, metrics, tracer)
+        rc = _snapshot_roundtrip(args, run.state, run.eng, metrics, tracer)
     _emit_report(args, metrics, tracer, mode="classification")
     return rc
 
@@ -327,13 +376,17 @@ def _snapshot_roundtrip(args, state, eng, metrics, tracer) -> int:
     else:
         store.save(args.steps, state, meta=eng.meta(), blocking=True)
     eng2, state2, step = store.restore_engine()
+    leaves2 = jax.tree_util.tree_leaves(state2)
     same = all(
         np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(jax.tree_util.tree_leaves(state),
-                        jax.tree_util.tree_leaves(state2)))
+        for a, b in zip(jax.tree_util.tree_leaves(state), leaves2))
+    # a sharded snapshot must come back on as many devices as it left
+    placed = eng2.shards == eng.shards and all(
+        len(a.sharding.device_set) == eng.shards for a in leaves2)
     print(f"[serve] snapshot@step {step} -> restore "
-          f"{'bit-exact' if same else 'MISMATCH'}")
-    return 0 if same else 1
+          f"{'bit-exact' if same else 'MISMATCH'} on {eng2.shards} "
+          f"shard(s){'' if placed else f' (saved with {eng.shards})'}")
+    return 0 if same and placed else 1
 
 
 def _serve_registry(args) -> int:
@@ -407,19 +460,20 @@ def _serve_registry(args) -> int:
     return 0
 
 
-def _serve_regression(args) -> int:
-    """Multi-tenant streaming regression CP on the regression engine."""
+def _run_regression(args, metrics, tracer):
+    """Build the regression engine, serve ``--steps`` ticks of seeded
+    drift traffic, then read exact prediction intervals for a fresh
+    query batch, every tenant in one dispatch."""
+    from types import SimpleNamespace
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.regression import RegressionServingEngine
 
-    metrics, tracer = _telemetry(args)
     S, T, dim = args.sessions, args.steps, args.dim
-    if T < 2:
-        raise SystemExit(
-            "--steps must be >= 2 (tick 0 is the compile warmup)")
+    _check_steps(args)
     _check_shards(args.shards, S)
     eng = RegressionServingEngine(
         n_sessions=S, capacity=args.capacity, dim=dim, k=args.k,
@@ -428,7 +482,8 @@ def _serve_regression(args) -> int:
     state = eng.init_state()
     metrics.gauge("serve_shards", mode="regression").set(args.shards)
     print(f"[serve] regression engine: {S} sessions x cap {args.capacity} "
-          f"(window={args.window}, k={args.k}, shards={args.shards})")
+          f"(window={args.window}, k={args.k}, shards={args.shards}, "
+          f"chunk={args.chunk}) on {_device_line()}")
 
     # per-tenant linear traffic y = <w_s, x> + noise; odd tenants change
     # their regression function at T/2 (streaming drift detection)
@@ -444,40 +499,37 @@ def _serve_regression(args) -> int:
     taus = jax.random.uniform(kt, (S, T), dtype=jnp.float32)
     X, y, taus = _chaos_traffic(args, X, y, taus, mode="regression")
     drv, guard = _maybe_guard(args, eng, state, metrics, tracer)
-
-    pvals = np.zeros((S, T), np.float32)
-    state, _ = drv.observe(  # warmup tick 0 outside the clock (compile)
-        state, X[:, 0], y[:, 0], taus[:, 0])
-    pvals[:, 0] = np.nan
-    t0 = time.time()
-    for t in range(1, T):
-        state, p = drv.observe(state, X[:, t], y[:, t], taus[:, t])
-        pvals[:, t] = np.asarray(p)
-    dt = time.time() - t0
+    state, pvals, warm, dt, rate = _drive(args, drv, state, X, y, taus)
     metrics.gauge("serve_wall_s", mode="regression").set(dt)
-    metrics.gauge("serve_session_steps_per_s", mode="regression").set(
-        S * (T - 1) / dt)
+    metrics.gauge("serve_session_steps_per_s", mode="regression").set(rate)
     eng.telemetry.drain()
     state = _drain_guard(guard, state)
 
-    warm = 2 * args.k  # k-NN warmup: earliest p-values are degenerate
-    _validity_metrics(pvals[:, warm:], drifted, args, engine="regression",
-                      metrics=metrics)
-
-    # exact prediction intervals for a fresh query batch, every tenant
-    # in one dispatch
     Xq = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
                            (4, dim), jnp.float32)
     iv = np.asarray(eng.intervals(state, Xq, epsilon=args.eps))
-    widths = iv[:, :, 1] - iv[:, :, 0]
+    return SimpleNamespace(eng=eng, state=state, pvals=pvals, X=X, y=y,
+                           taus=taus, drifted=drifted, warmup_s=warm,
+                           steps_per_s=rate, Xq=Xq, intervals=iv)
+
+
+def _serve_regression(args) -> int:
+    """Multi-tenant streaming regression CP on the regression engine."""
+    import numpy as np
+
+    metrics, tracer = _telemetry(args)
+    run = _run_regression(args, metrics, tracer)
+    warm = 2 * args.k  # k-NN warmup: earliest p-values are degenerate
+    _validity_metrics(run.pvals[:, warm:], run.drifted, args,
+                      engine="regression", metrics=metrics)
+    iv = run.intervals
     metrics.gauge("intervals_finite_frac", engine="regression").set(
         float(np.isfinite(iv).mean()))
     metrics.gauge("intervals_median_width", engine="regression").set(
-        float(np.nanmedian(widths)))
-
+        float(np.nanmedian(iv[:, :, 1] - iv[:, :, 0])))
     rc = 0
     if args.snapshot_dir:
-        rc = _snapshot_roundtrip(args, state, eng, metrics, tracer)
+        rc = _snapshot_roundtrip(args, run.state, run.eng, metrics, tracer)
     _emit_report(args, metrics, tracer, mode="regression")
     return rc
 
@@ -592,7 +644,7 @@ def _serve_replay(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -610,6 +662,9 @@ def main(argv=None) -> int:
     ap.add_argument("--k", type=int, default=7)
     ap.add_argument("--capacity", type=int, default=128)
     ap.add_argument("--window", type=int, default=64)
+    ap.add_argument("--chunk", type=int, default=1,
+                    help="engine modes: ticks per observe_many dispatch "
+                         "(--steps must be a multiple)")
     ap.add_argument("--drift", type=float, default=2.0)
     ap.add_argument("--log-threshold", type=float, default=2.0)
     ap.add_argument("--snapshot-dir", default="")
@@ -693,13 +748,21 @@ def main(argv=None) -> int:
                          "nonzero exit on any violation")
     ap.add_argument("--audit-out", default="audit_report.json",
                     help="with --audit: JSON report path")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = _parser().parse_args(argv)
 
     if args.audit:
         from repro.analysis import audit as audit_m
         return audit_m.main(
             ["--out", args.audit_out, "--no-reexec",
              "--max-shards", str(max(args.shards, 1))])
+    if args.chunk < 1:
+        raise SystemExit("--chunk must be >= 1")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.replay:
         if args.measure:
             raise SystemExit("--replay and --measure are exclusive")

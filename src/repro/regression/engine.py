@@ -361,11 +361,8 @@ class RegressionServingEngine:
             raise ValueError(f"not a regression-engine meta: mode={mode!r}")
         meta.pop("n_labels", None)  # tolerate classification-era keys
         meta["dtype"] = jnp.dtype(meta.get("dtype", "float32"))
-        # restore sharded only when this host can honour it
-        shards = int(meta.pop("shards", 1))
-        if (shards > 1 and shards <= jax.device_count()
-                and meta["n_sessions"] % shards == 0):
-            meta["shards"] = shards
+        meta["shards"] = engine_utils.restorable_shards(
+            int(meta.pop("shards", 1)), meta["n_sessions"])
         return cls(**meta)
 
 

@@ -353,13 +353,8 @@ class ServingEngine:
     def from_meta(cls, meta: dict[str, Any]) -> "ServingEngine":
         meta = dict(meta)
         meta["dtype"] = jnp.dtype(meta.get("dtype", "float32"))
-        # a snapshot from a sharded fleet restores wherever it lands:
-        # fall back to a single device when the saved shard count is
-        # not available here (results are bit-identical either way)
-        shards = int(meta.pop("shards", 1))
-        if (shards > 1 and shards <= jax.device_count()
-                and meta["n_sessions"] % shards == 0):
-            meta["shards"] = shards
+        meta["shards"] = engine_utils.restorable_shards(
+            int(meta.pop("shards", 1)), meta["n_sessions"])
         return cls(**meta)
 
 

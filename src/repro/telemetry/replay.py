@@ -124,12 +124,13 @@ def _plan_batches(records: list[dict[str, Any]],
     """Group record indices into dispatch batches.
 
     Read ops and multi-tick observe_many records dispatch alone;
-    consecutive single-tick observes coalesce up to ``chunk``.
+    consecutive single-tick drive records (``observe``, or a one-tick
+    ``observe_many``) coalesce up to ``chunk``.
     """
     batches: list[list[int]] = []
     run: list[int] = []
     for i, rec in enumerate(records):
-        single_obs = rec["op"] == "observe" and rec.get("ticks", 1) == 1
+        single_obs = rec["op"] in _DRIVE_OPS and rec.get("ticks", 1) == 1
         if chunk and chunk > 1 and single_obs:
             run.append(i)
             if len(run) >= chunk:

@@ -225,9 +225,8 @@ _AUDIT_SCRIPT = _PRELUDE + textwrap.dedent("""
     # sabotage: a psum smuggled into a shard_map'd tick is caught with
     # the offending HLO op named
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(jax.devices(), ("tenants",))
-    bad = jax.jit(shard_map(
+    bad = jax.jit(jax.shard_map(
         lambda x: x + jax.lax.psum(x, "tenants"), mesh=mesh,
         in_specs=P("tenants"), out_specs=P("tenants")))
     text = bad.lower(jnp.ones((8, 4), jnp.float32)).compile().as_text()

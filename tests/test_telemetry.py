@@ -432,8 +432,8 @@ def test_serve_classification_e2e_trace_and_metrics(tmp_path):
     assert rc == 0
     recs = validate_trace_file(trace)
     ops = {r["op"] for r in recs}
-    assert {"observe", "snapshot_save", "snapshot_restore"} <= ops
-    compiles = [r for r in recs if r["op"] == "observe" and r["compile"]]
+    assert {"observe_many", "snapshot_save", "snapshot_restore"} <= ops
+    compiles = [r for r in recs if r["op"] == "observe_many" and r["compile"]]
     assert len(compiles) == 1  # one signature -> one compile record
     d = json.load(open(mout))
     names = {m["name"] for m in d["metrics"]}
@@ -452,9 +452,41 @@ def test_serve_regression_e2e(tmp_path):
         "--trace-out", trace])
     assert rc == 0
     recs = validate_trace_file(trace)
-    assert {"observe", "intervals"} <= {r["op"] for r in recs}
+    assert {"observe_many", "intervals"} <= {r["op"] for r in recs}
     assert all(r["engine"] == "regression" for r in recs
-               if r["op"] == "observe")
+               if r["op"] == "observe_many")
+
+
+@pytest.mark.parametrize("steps,chunk", [(20, 16), (16, 16), (18, 4)])
+def test_serve_rejects_steps_not_whole_chunks(steps, chunk):
+    from repro.launch import serve
+
+    with pytest.raises(SystemExit, match="multiple of --chunk"):
+        serve.main(["--sessions", "2", "--steps", str(steps),
+                    "--chunk", str(chunk), "--window", "6",
+                    "--capacity", "16", "--dim", "3", "--k", "3"])
+
+
+@pytest.mark.parametrize("mode", ["classification", "regression"])
+def test_serve_chunked_drive_matches_per_tick(mode):
+    """``--chunk 4`` serves the same p-values and state as one tick per
+    dispatch (the launcher's side of the observe_many property)."""
+    from repro.launch import serve
+
+    run = (serve._run_regression if mode == "regression"
+           else serve._run_sessions)
+    out = []
+    for chunk in (1, 4):
+        argv = ["--sessions", "3", "--steps", "16", "--window", "6",
+                "--capacity", "16", "--dim", "3", "--k", "3",
+                "--chunk", str(chunk)]
+        args = serve._parser().parse_args(argv)
+        out.append(run(args, *serve._telemetry(args)))
+    one, many = out
+    np.testing.assert_array_equal(one.pvals, many.pvals)
+    for a, b in zip(jax.tree_util.tree_leaves(one.state),
+                    jax.tree_util.tree_leaves(many.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_serve_registry_e2e(tmp_path):

@@ -1,0 +1,139 @@
+"""Compile-only checks for a TPU v5e chip that is described, not attached.
+
+Each conformal-measure and serving kernel (``stream_update``,
+``cp_update``, ``interval_sweep``, ``pairwise_dist``, ``kde_score``),
+and one whole ``observe_many`` chunk of each serving engine, is lowered
+and compiled for one chip of a ``v5e:2x2`` topology at the paper's
+Section 7.1 widths (capacity 1024,
+30 features, k 15). The TPU compiler refuses here what interpret mode
+cannot see (integer-only ops, tiling, memory), so these run on every
+change without a chip. Each compiled program must hold the kernel as a
+``tpu_custom_call``.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+CAP, DIM, K, M = 1024, 30, 15, 8
+TENANTS, CHUNK = 256, 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU lib"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_route(monkeypatch):
+    """Send ``kernels.ops`` down its TPU branch while tracing, with no
+    trace cached from (or left for) the CPU route."""
+    from repro.kernels import ops
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    yield
+    jax.clear_caches()
+
+
+def _spec(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compiled_text(fn, *args, **kw) -> str:
+    text = fn.lower(*args, **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("mode", ["class", "reg"])
+@pytest.mark.parametrize("ring", [False, True], ids=["linear", "ring"])
+def test_stream_update_compiles(one_chip, mode, ring):
+    from repro.kernels.stream_update import stream_update
+
+    s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    y = s((CAP,), jnp.int32 if mode == "class" else jnp.float32)
+    y_new = s((), jnp.int32 if mode == "class" else jnp.float32)
+    ring_kw = (dict(head=s((), jnp.int32), wrap=s((), jnp.int32))
+               if ring else {})
+    _compiled_text(stream_update, s((CAP, DIM)), y, s((CAP, K)),
+                   s((CAP, K)), s((DIM,)), y_new, s((), jnp.int32),
+                   mode=mode, **ring_kw)
+
+
+def test_cp_update_compiles(one_chip):
+    from repro.kernels.cp_update import cp_knn_counts
+
+    s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    _compiled_text(cp_knn_counts, s((CAP, DIM)), s((CAP,), jnp.int32),
+                   s((CAP,)), s((CAP,)), s((M, DIM)), s((M, 2)),
+                   n_labels=2)
+
+
+def test_interval_sweep_compiles(one_chip):
+    from repro.kernels.interval_sweep import interval_sweep
+
+    s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    _compiled_text(interval_sweep, s((CAP, DIM)), s((CAP,)), s((CAP,)),
+                   s((CAP,)), s((CAP,), jnp.bool_), s((M, DIM)), s((M,)),
+                   k=K)
+
+
+@pytest.mark.parametrize("m", [M, CAP])
+def test_pairwise_dist_compiles(one_chip, m):
+    from repro.kernels.pairwise_dist import pairwise_sq_dists
+
+    _compiled_text(pairwise_sq_dists, _spec(one_chip, (m, DIM)),
+                   _spec(one_chip, (CAP, DIM)))
+
+
+def test_kde_score_compiles(one_chip):
+    from repro.kernels.kde_score import kde_rowsums
+
+    s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    _compiled_text(kde_rowsums, s((M, DIM)), s((CAP, DIM)),
+                   s((M,), jnp.int32), s((CAP,), jnp.int32), h=1.0)
+
+
+@pytest.mark.parametrize("family", ["classification", "regression"])
+def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
+    """One served ``observe_many`` chunk at 256 tenants x window 1024:
+    the observe kernel is inside, and the donated state aliases."""
+    if family == "classification":
+        from repro.serving import ServingEngine as Engine
+        extra, ydt = dict(n_labels=2), jnp.int32
+    else:
+        from repro.regression import RegressionServingEngine as Engine
+        extra, ydt = {}, jnp.float32
+    eng = Engine(n_sessions=TENANTS, capacity=CAP, dim=DIM, k=K,
+                 window=CAP, instrument=True, **extra)
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(eng.init_state))
+    s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    compiled = eng._step_many.lower(
+        state, s((CHUNK, TENANTS, DIM)), s((CHUNK, TENANTS), ydt),
+        s((CHUNK, TENANTS)), s((TENANTS,), jnp.int32),
+        s((CHUNK, TENANTS), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(state))
+    assert mem.alias_size_in_bytes >= state_bytes  # donation holds
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 2 ** 30)  # fits one v5e chip's HBM
