@@ -4,19 +4,22 @@
 `regression.engine.RegressionServingEngine` differ only in their state
 pytree and per-tick step; the stateful host-side logic around the jitted
 dispatch — grow-mode capacity provisioning, the sliding-window occupancy
-invariant, and the scan-chunk wrapper — is identical and easy to let
-drift apart. It lives here once, parameterized on an ``n_of`` accessor
-that reads the per-session occupancy array from the engine's state.
+invariant, the scan-chunk wrapper, and the tick and read dispatches
+with their host spans — is identical and easy to let drift apart. It
+lives here once, parameterized on an ``n_of`` accessor that reads the
+per-session occupancy array from the engine's state.
 (This module is import-neutral: both engine modules can use it without
 touching the ``repro.serving`` package __init__, which would be
 circular.)
 """
 from __future__ import annotations
 
+import contextlib
 import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 
 def scan_chunk(vstep, stats_fn=None):
@@ -42,7 +45,8 @@ def scan_chunk(vstep, stats_fn=None):
     """
     def chunk(state, xs, ys, taus, windows, actives):
         if stats_fn is not None:
-            st = stats_fn(state, windows, actives)
+            with jax.named_scope("stats"):
+                st = stats_fn(state, windows, actives)
 
         def body(s, inp):
             x, y, tau, act = inp
@@ -52,6 +56,74 @@ def scan_chunk(vstep, stats_fn=None):
         return (out, (ps, st)) if stats_fn is not None else (out, ps)
 
     return chunk
+
+
+class DispatchSpans:
+    """Host spans of an engine's dispatches, on the profiler's clock.
+
+    ``with spans("observe_many") as span:`` opens ``repro.observe_many``
+    and yields ``span(phase)``, which opens ``repro.<phase>`` inside it
+    (``prepare``, ``launch``, ``fold``). Every span of one dispatch
+    carries that dispatch's sequence number as its ``seq`` argument, so
+    a chunk's spans share one identifier in a trace. The spans are
+    ``jax.profiler.TraceAnnotation``s: written only while a profile is
+    being captured, a no-op check otherwise, with or without telemetry.
+    """
+
+    def __init__(self):
+        self.seq = 0
+
+    @contextlib.contextmanager
+    def __call__(self, op: str):
+        self.seq += 1
+        seq = self.seq
+        with TraceAnnotation(f"repro.{op}", seq=seq):
+            yield lambda phase: TraceAnnotation(f"repro.{phase}", seq=seq)
+
+
+def dispatch_chunk(eng, state, xs, ys, taus, active, *, op: str, n_of,
+                   y_dtype):
+    """The engines' shared observe/observe_many dispatch, under the host
+    spans ``repro.<op>`` > ``prepare`` (room, invariants, the chunk's
+    arguments), ``launch`` (the jitted chunk) and, when instrumented,
+    ``fold`` (the tick stats into their accumulator)."""
+    with eng._spans(op) as span:
+        with span("prepare"):
+            if active is None:
+                active = jnp.ones(xs.shape[:2], dtype=bool)
+            state = ensure_room(eng, state, xs.shape[0], n_of)
+            check_window_occupancy(eng, state, n_of, lambda s: s.wrap)
+            args = (state, xs, ys.astype(y_dtype), taus.astype(eng.dtype),
+                    eng._windows(state), active)
+        if eng.telemetry is None:
+            with span("launch"):
+                return eng._step_many(*args)
+        T, S = xs.shape[:2]
+        with eng.telemetry.timed(op, signature=(xs.shape, eng.capacity),
+                                 ticks=T, tenants=S,
+                                 capacity=eng.capacity) as tm:
+            with span("launch"):
+                state, (p, stats) = eng._step_many(*args)
+            tm.sync(p)
+        with span("fold"):
+            eng.telemetry.ticks.fold(stats)
+        return state, p
+
+
+def dispatch_read(eng, op: str, fn, *args):
+    """One read dispatch ``fn(state, X_test, ...)`` under the host spans
+    ``repro.<op>`` > ``launch``, timed by the telemetry when
+    instrumented."""
+    with eng._spans(op) as span:
+        if eng.telemetry is None:
+            with span("launch"):
+                return fn(*args)
+        with eng.telemetry.timed(op, signature=(args[1].shape, eng.capacity),
+                                 tenants=eng.n_sessions,
+                                 capacity=eng.capacity) as tm:
+            with span("launch"):
+                out = fn(*args)
+            return tm.sync(out)
 
 
 def ensure_room(eng, state, ticks: int, n_of):
@@ -148,5 +220,5 @@ def restorable_shards(shards: int, n_sessions: int) -> int:
     return 1
 
 
-__all__ = ["scan_chunk", "ensure_room", "check_window_occupancy",
-           "restorable_shards"]
+__all__ = ["scan_chunk", "DispatchSpans", "dispatch_chunk", "dispatch_read",
+           "ensure_room", "check_window_occupancy", "restorable_shards"]

@@ -93,5 +93,6 @@ def cp_knn_counts(
         out_specs=pl.BlockSpec((bm, n_labels), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n_labels), jnp.int32),
         interpret=interpret,
+        name="cp_update",
     )(Xtp, Xp, yp, sp, kp, ap)
     return out[:m]

@@ -116,5 +116,6 @@ def interval_sweep(
             jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         ],
         interpret=interpret,
+        name="interval_sweep",
     )(Xtp, atp, Xp, app, kdp, klp, lvp)
     return lo[:m, :n], hi[:m, :n]

@@ -71,5 +71,6 @@ def pairwise_sq_dists(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="pairwise_sq_dists",
     )(Ap, Bp)
     return out[:m, :n]

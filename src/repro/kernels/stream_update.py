@@ -165,5 +165,6 @@ def stream_update(
             jax.ShapeDtypeStruct((capp, k), jnp.float32),
         ],
         interpret=interpret,
+        name="stream_update",
     )(scal, xp, Xp, yp, ndp, nyp)
     return d[:cap, 0], nd2[:cap], ny2[:cap]

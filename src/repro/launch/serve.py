@@ -49,6 +49,10 @@ text export. ``--metrics-out`` dumps the same snapshot as JSON and
     python -m repro.launch.serve --sessions 8 --steps 64 \\
         --metrics-out metrics.json --trace-out trace.jsonl
 
+A profile of the run (``jax.profiler.trace``) holds the engines' own
+host spans (``repro.<op>``, see ``core.engine_utils.DispatchSpans``) and
+the named scopes of their programs, with or without these flags.
+
 ``--replay TRACE`` turns the launcher into a load-test driver
 (``repro.telemetry.replay``): TRACE is either a recorded JSONL trace
 file or a ``loadgen:<workload>`` spec (steady / bursty / diurnal /
@@ -195,8 +199,7 @@ def _telemetry(args):
     from repro.telemetry import MetricsRegistry, Tracer
 
     metrics = MetricsRegistry()
-    tracer = (Tracer(args.trace_out, annotate=args.annotate)
-              if args.trace_out else None)
+    tracer = Tracer(args.trace_out) if args.trace_out else None
     return metrics, tracer
 
 
@@ -719,9 +722,6 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--trace-out", default="",
                     help="record one JSONL trace record per engine op to "
                          "this file (schema: repro.telemetry.tracer)")
-    ap.add_argument("--annotate", action="store_true",
-                    help="with --trace-out: wrap traced ops in "
-                         "jax.profiler.TraceAnnotation scopes")
     # chaos / fault tolerance (repro.robustness)
     ap.add_argument("--faults", type=int, default=-1, metavar="SEED",
                     help="inject a keyed random fault plan (repro."

@@ -164,6 +164,7 @@ class RegressionServingEngine:
         self._pvalues = jax.jit(pvals)
         self._intervals = jax.jit(ivals)
         self._n_bound: int | None = None
+        self._spans = engine_utils.DispatchSpans()
 
     # -- state --------------------------------------------------------------
 
@@ -209,10 +210,9 @@ class RegressionServingEngine:
         of ``observe_many`` (bit-identical, tested); with ``donate=True``
         (default) the input ``state`` is consumed.
         """
-        if active is None:
-            active = jnp.ones((self.n_sessions,), dtype=bool)
         state, p = self._dispatch(
-            state, x[None], y[None], tau[None], active[None], op="observe")
+            state, x[None], y[None], tau[None],
+            None if active is None else active[None], op="observe")
         return state, p[0]
 
     def observe_many(self, state: RegStreamState, xs, ys, taus,
@@ -228,30 +228,16 @@ class RegressionServingEngine:
         never needs a mid-chunk host sync. With ``donate=True`` the
         input ``state`` is consumed.
         """
-        if active is None:
-            active = jnp.ones(xs.shape[:2], dtype=bool)
         return self._dispatch(state, xs, ys, taus, active,
                               op="observe_many")
 
     def _dispatch(self, state: RegStreamState, xs, ys, taus, active, *,
                   op: str):
-        """The shared observe/observe_many dispatch (telemetry-aware)."""
-        state = engine_utils.ensure_room(self, state, xs.shape[0],
-                                         lambda s: s.n)
-        engine_utils.check_window_occupancy(self, state, lambda s: s.n,
-                                            lambda s: s.wrap)
-        args = (state, xs, ys.astype(self.dtype), taus.astype(self.dtype),
-                self._windows(state), active)
-        if self.telemetry is None:
-            return self._step_many(*args)
-        T, S = xs.shape[:2]
-        with self.telemetry.timed(op, signature=(xs.shape, self.capacity),
-                                  ticks=T, tenants=S,
-                                  capacity=self.capacity) as tm:
-            state, (p, stats) = self._step_many(*args)
-            tm.sync(p)
-        self.telemetry.ticks.fold(stats)
-        return state, p
+        """The shared observe/observe_many dispatch (telemetry-aware,
+        under the engine's host spans)."""
+        return engine_utils.dispatch_chunk(
+            self, state, xs, ys, taus, active, op=op,
+            n_of=lambda s: s.n, y_dtype=self.dtype)
 
     def lower_tick(self, ticks: int = 4):
         """Lower (but do NOT execute) a ``ticks``-long observe_many chunk.
@@ -271,6 +257,15 @@ class RegressionServingEngine:
         active = jnp.ones((T, S), dtype=bool)
         return self._step_many.lower(state, xs, ys, taus,
                                      self._windows(state), active)
+
+    def lower_read(self, queries: int = 1, epsilon: float = 0.1):
+        """Lower (but do NOT execute) the read program, ``intervals`` over
+        ``queries`` query points a tenant: the read's compiled form, as
+        ``lower_tick`` gives the tick's (a profile names the compiled
+        instructions; their ``op_name`` metadata holds the named scopes)."""
+        X = jnp.zeros((self.n_sessions, queries, self.dim), self.dtype)
+        return self._intervals.lower(self.init_state(), X,
+                                     jnp.asarray(epsilon, self.dtype))
 
     def reset_occupancy(self) -> None:
         """Forget the host-side occupancy bound (grow mode) and the
@@ -316,13 +311,8 @@ class RegressionServingEngine:
             X_test = jnp.broadcast_to(
                 X_test, (self.n_sessions,) + X_test.shape)
         eps = jnp.asarray(epsilon, self.dtype)
-        if self.telemetry is None:
-            return self._intervals(state, X_test, eps)
-        with self.telemetry.timed("intervals",
-                                  signature=(X_test.shape, self.capacity),
-                                  tenants=self.n_sessions,
-                                  capacity=self.capacity) as tm:
-            return tm.sync(self._intervals(state, X_test, eps))
+        return engine_utils.dispatch_read(self, "intervals", self._intervals,
+                                          state, X_test, eps)
 
     def pvalues(self, state: RegStreamState, X_test,
                 t_query) -> jnp.ndarray:
@@ -330,13 +320,8 @@ class RegressionServingEngine:
         if X_test.ndim == 2:
             X_test = jnp.broadcast_to(
                 X_test, (self.n_sessions,) + X_test.shape)
-        if self.telemetry is None:
-            return self._pvalues(state, X_test, t_query)
-        with self.telemetry.timed("pvalues",
-                                  signature=(X_test.shape, self.capacity),
-                                  tenants=self.n_sessions,
-                                  capacity=self.capacity) as tm:
-            return tm.sync(self._pvalues(state, X_test, t_query))
+        return engine_utils.dispatch_read(self, "pvalues", self._pvalues,
+                                          state, X_test, t_query)
 
     # -- snapshot -----------------------------------------------------------
 

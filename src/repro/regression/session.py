@@ -152,72 +152,78 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
     head, n = state.head, state.n
     act = jnp.asarray(active)
 
-    if evictable:
-        ev = act & (n >= window)
-        s = ev.astype(jnp.int32)
-        dcol = Dw[:, head]
-        head1 = _mod_cap(head + s, wrap)
-        n1 = n - s
-        live1 = ring_live(w, head1, n1, wrap)
-        affected = ev & live1 & (dcol <= state.nbr_d[:w, -1])
-        nbr_d1, nbr_y1, nbr_a1 = drop_backfill(
-            state.nbr_d[:w], dcol, live1[None, :], Dw, affected, k=k,
-            Ly=state.nbr_y[:w], La=state.nbr_a[:w], ys=yw, aid=aidw,
-            age=ring_age(w, head1, wrap), slots=ring_slots(w, head1, wrap),
-            aid0=aidw[head])
-    else:
-        head1, n1 = head, n
-        nbr_d1, nbr_y1 = state.nbr_d[:w], state.nbr_y[:w]
-        nbr_a1 = state.nbr_a[:w]
-        live1 = ring_live(w, head1, n1, wrap)
+    # named scopes (op_name metadata only: same instructions, same
+    # bits) split the tick's device time by stage in a profile
+    with jax.named_scope("evict"):
+        if evictable:
+            ev = act & (n >= window)
+            s = ev.astype(jnp.int32)
+            dcol = Dw[:, head]
+            head1 = _mod_cap(head + s, wrap)
+            n1 = n - s
+            live1 = ring_live(w, head1, n1, wrap)
+            affected = ev & live1 & (dcol <= state.nbr_d[:w, -1])
+            nbr_d1, nbr_y1, nbr_a1 = drop_backfill(
+                state.nbr_d[:w], dcol, live1[None, :], Dw, affected, k=k,
+                Ly=state.nbr_y[:w], La=state.nbr_a[:w], ys=yw, aid=aidw,
+                age=ring_age(w, head1, wrap),
+                slots=ring_slots(w, head1, wrap), aid0=aidw[head])
+        else:
+            head1, n1 = head, n
+            nbr_d1, nbr_y1 = state.nbr_d[:w], state.nbr_y[:w]
+            nbr_a1 = state.nbr_a[:w]
+            live1 = ring_live(w, head1, n1, wrap)
 
     # learn (mirrors stream._observe, writes gated on ``active``)
-    idx = _mod_cap(head1 + n1, wrap)
-    y_new = jnp.asarray(y_new, yw.dtype)
-    d_row, nbr_d_m, nbr_y_m = kops.stream_update(
-        Xw, yw, nbr_d1, nbr_y1, x_new, y_new, n1, mode="reg", head=head1,
-        wrap=wrap)
-    row = jnp.where(act, d_row, Dw[idx, :])  # D symmetric: row == col
-    # bit-neutral scheduling marker (see serving.session._sliding_step):
-    # the in-place D update must depend on every repaired list (each
-    # carries backfill reads of D) or XLA copies the donated (cap, cap)
-    # buffer twice per tick. Distances are finite and >= 0 and labels
-    # and ids finite, so the term is exactly +0.0
-    row = row + (nbr_d1[0, 0]
-                 + (nbr_y1[0, 0] + nbr_a1[0, 0]) * 0.0) * 0.0
-    D2 = state.D.at[idx, :w].set(row).at[:w, idx].set(row)
-    y2w = yw.at[idx].set(jnp.where(act, y_new, yw[idx]))
-    sub = RegStreamState(Xw, yw, Dw, nbr_d1, nbr_y1, n1, head1, aidw,
-                         wrap, nbr_a1)
-    own_d, own_y, y_sel, own_a = stream._own_list(sub, d_row, y2w, y_new,
-                                                  k=k)
-    new_aid = _next_aid(aidw, head1, n1, wrap)
-    enters = live1 & (d_row < nbr_d1[:, -1])
-    nbr_a_m = stream._merge_aid(nbr_d1, nbr_a1,
-                                jnp.where(enters, d_row, BIG), new_aid,
-                                nbr_d_m)
-    new_state = RegStreamState(
-        X=state.X.at[idx].set(jnp.where(act, x_new, Xw[idx])),
-        y=state.y.at[idx].set(jnp.where(act, y_new, yw[idx])),
-        D=D2,
-        nbr_d=state.nbr_d.at[:w].set(
-            jnp.where(act, nbr_d_m.at[idx].set(own_d), nbr_d1)),
-        nbr_y=state.nbr_y.at[:w].set(
-            jnp.where(act, nbr_y_m.at[idx].set(own_y), nbr_y1)),
-        n=n1 + act,
-        head=head1,
-        aid=state.aid.at[idx].set(
-            jnp.where(act, new_aid, state.aid[idx])),
-        wrap=wrap,
-        nbr_a=state.nbr_a.at[:w].set(
-            jnp.where(act, nbr_a_m.at[idx].set(own_a), nbr_a1)),
-    )
+    with jax.named_scope("learn"):
+        idx = _mod_cap(head1 + n1, wrap)
+        y_new = jnp.asarray(y_new, yw.dtype)
+        d_row, nbr_d_m, nbr_y_m = kops.stream_update(
+            Xw, yw, nbr_d1, nbr_y1, x_new, y_new, n1, mode="reg",
+            head=head1, wrap=wrap)
+        y2w = yw.at[idx].set(jnp.where(act, y_new, yw[idx]))
+        sub = RegStreamState(Xw, yw, Dw, nbr_d1, nbr_y1, n1, head1, aidw,
+                             wrap, nbr_a1)
+        own_d, own_y, y_sel, own_a = stream._own_list(sub, d_row, y2w,
+                                                      y_new, k=k)
+        new_aid = _next_aid(aidw, head1, n1, wrap)
+        enters = live1 & (d_row < nbr_d1[:, -1])
+        nbr_a_m = stream._merge_aid(nbr_d1, nbr_a1,
+                                    jnp.where(enters, d_row, BIG), new_aid,
+                                    nbr_d_m)
+        # price the observed label against the pre-learn window (mirrors
+        # ``_observe``'s p-value block bit-for-bit)
+        p = _price(d_row, y_sel, y_new, tau, k=k, live=live1,
+                   nbr_d=nbr_d1, nbr_y=nbr_y1, y=yw, n=n1)
 
-    # price the observed label against the pre-learn window (mirrors
-    # ``_observe``'s p-value block bit-for-bit)
-    p = _price(d_row, y_sel, y_new, tau, k=k, live=live1,
-               nbr_d=nbr_d1, nbr_y=nbr_y1, y=yw, n=n1)
-    p = jnp.where(act, p, jnp.asarray(jnp.nan, dtype=Xw.dtype))
+    with jax.named_scope("write"):
+        row = jnp.where(act, d_row, Dw[idx, :])  # D symmetric: row == col
+        # bit-neutral scheduling marker (see
+        # serving.session._sliding_step): the in-place D update must
+        # depend on every repaired list (each carries backfill reads of
+        # D) or XLA copies the donated (cap, cap) buffer twice per tick.
+        # Distances are finite and >= 0 and labels and ids finite, so the
+        # term is exactly +0.0
+        row = row + (nbr_d1[0, 0]
+                     + (nbr_y1[0, 0] + nbr_a1[0, 0]) * 0.0) * 0.0
+        D2 = state.D.at[idx, :w].set(row).at[:w, idx].set(row)
+        new_state = RegStreamState(
+            X=state.X.at[idx].set(jnp.where(act, x_new, Xw[idx])),
+            y=state.y.at[idx].set(jnp.where(act, y_new, yw[idx])),
+            D=D2,
+            nbr_d=state.nbr_d.at[:w].set(
+                jnp.where(act, nbr_d_m.at[idx].set(own_d), nbr_d1)),
+            nbr_y=state.nbr_y.at[:w].set(
+                jnp.where(act, nbr_y_m.at[idx].set(own_y), nbr_y1)),
+            n=n1 + act,
+            head=head1,
+            aid=state.aid.at[idx].set(
+                jnp.where(act, new_aid, state.aid[idx])),
+            wrap=wrap,
+            nbr_a=state.nbr_a.at[:w].set(
+                jnp.where(act, nbr_a_m.at[idx].set(own_a), nbr_a1)),
+        )
+        p = jnp.where(act, p, jnp.asarray(jnp.nan, dtype=Xw.dtype))
     return new_state, p
 
 
@@ -389,38 +395,45 @@ def intervals(state: RegStreamState, X_test, *, k, epsilon):
     path on the live window — the fully-batched form differs by ~1 ulp
     in the endpoints through different FMA contraction.
     """
-    Xg, yg, a_prime, upd, kth, kth_label, live = _arrival_stats(state,
-                                                                k=k)
+    with jax.named_scope("gather"):
+        Xg, yg, a_prime, upd, kth, kth_label, live = _arrival_stats(
+            state, k=k)
     thresh = epsilon * (state.n + 1.0) - 1.0
 
     if kops.pallas_active(state.X.dtype):
-        d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, Xg), 0.0))
-        dm = jnp.where(live[None, :], d, BIG)
-        _, idx = jax.lax.top_k(-dm, k)
-        a_test = -jnp.sum(yg[idx], axis=1) / k
-        lo, hi = kops.interval_sweep(
-            Xg, a_prime, kth, kth_label, live, X_test, a_test, k)
+        with jax.named_scope("query"):
+            d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, Xg), 0.0))
+            dm = jnp.where(live[None, :], d, BIG)
+            _, idx = jax.lax.top_k(-dm, k)
+            a_test = -jnp.sum(yg[idx], axis=1) / k
+        with jax.named_scope("sweep"):
+            lo, hi = kops.interval_sweep(
+                Xg, a_prime, kth, kth_label, live, X_test, a_test, k)
 
         def sweep(lo_r, hi_r):
             return jnp.stack(hull_sweep(lo_r, hi_r, lo_r > hi_r, thresh))
 
-        return jax.vmap(sweep)(lo, hi)
+        with jax.named_scope("hull"):
+            return jax.vmap(sweep)(lo, hi)
 
     def per_test(x_t):
-        d_t = jnp.sqrt(jnp.maximum(
-            kops.sq_dists(x_t[None], Xg)[0], 0.0))
-        enters = live & (d_t < kth)
-        # ``upd`` comes precomputed from the barriered stats block —
-        # recomputing a_prime + kth_label/k here re-fuses with the map
-        # body and rounds 1 ulp away from the batch path's bits
-        a_vec = jnp.where(enters, upd, a_prime)
-        b_vec = jnp.where(enters, -1.0 / k, 0.0)
-        dm = jnp.where(live, d_t, BIG)
-        _, idx = jax.lax.top_k(-dm, k)
-        a = -jnp.sum(yg[idx]) / k
-        lo, hi = jax.vmap(_interval_ge, in_axes=(0, 0, None))(
-            a_vec, b_vec, a)
-        return jnp.stack(hull_sweep(lo, hi, (lo > hi) | ~live, thresh))
+        with jax.named_scope("query"):
+            d_t = jnp.sqrt(jnp.maximum(
+                kops.sq_dists(x_t[None], Xg)[0], 0.0))
+            enters = live & (d_t < kth)
+            # ``upd`` comes precomputed from the barriered stats block —
+            # recomputing a_prime + kth_label/k here re-fuses with the
+            # map body and rounds 1 ulp away from the batch path's bits
+            a_vec = jnp.where(enters, upd, a_prime)
+            b_vec = jnp.where(enters, -1.0 / k, 0.0)
+            dm = jnp.where(live, d_t, BIG)
+            _, idx = jax.lax.top_k(-dm, k)
+            a = -jnp.sum(yg[idx]) / k
+        with jax.named_scope("sweep"):
+            lo, hi = jax.vmap(_interval_ge, in_axes=(0, 0, None))(
+                a_vec, b_vec, a)
+        with jax.named_scope("hull"):
+            return jnp.stack(hull_sweep(lo, hi, (lo > hi) | ~live, thresh))
 
     return jax.lax.map(per_test, X_test)
 

@@ -178,6 +178,7 @@ class ServingEngine:
         # host-side upper bound on max_s n_s, for grow-mode occupancy
         # checks without a per-tick device sync
         self._n_bound: int | None = None
+        self._spans = engine_utils.DispatchSpans()
 
     # -- state --------------------------------------------------------------
 
@@ -223,10 +224,9 @@ class ServingEngine:
         The T=1 case of ``observe_many`` (bit-identical, tested); with
         ``donate=True`` (default) the input ``state`` is consumed.
         """
-        if active is None:
-            active = jnp.ones((self.n_sessions,), dtype=bool)
         state, p = self._dispatch(
-            state, x[None], y[None], tau[None], active[None], op="observe")
+            state, x[None], y[None], tau[None],
+            None if active is None else active[None], op="observe")
         return state, p[0]
 
     def observe_many(self, state: Session, xs, ys, taus, active=None):
@@ -241,29 +241,15 @@ class ServingEngine:
         never needs a mid-chunk host sync. With ``donate=True`` the
         input ``state`` is consumed.
         """
-        if active is None:
-            active = jnp.ones(xs.shape[:2], dtype=bool)
         return self._dispatch(state, xs, ys, taus, active,
                               op="observe_many")
 
     def _dispatch(self, state: Session, xs, ys, taus, active, *, op: str):
-        """The shared observe/observe_many dispatch (telemetry-aware)."""
-        state = engine_utils.ensure_room(self, state, xs.shape[0],
-                                         lambda s: s.knn.n)
-        engine_utils.check_window_occupancy(self, state, lambda s: s.knn.n,
-                                            lambda s: s.wrap)
-        args = (state, xs, ys.astype(jnp.int32), taus.astype(self.dtype),
-                self._windows(state), active)
-        if self.telemetry is None:
-            return self._step_many(*args)
-        T, S = xs.shape[:2]
-        with self.telemetry.timed(op, signature=(xs.shape, self.capacity),
-                                  ticks=T, tenants=S,
-                                  capacity=self.capacity) as tm:
-            state, (p, stats) = self._step_many(*args)
-            tm.sync(p)
-        self.telemetry.ticks.fold(stats)
-        return state, p
+        """The shared observe/observe_many dispatch (telemetry-aware,
+        under the engine's host spans)."""
+        return engine_utils.dispatch_chunk(
+            self, state, xs, ys, taus, active, op=op,
+            n_of=lambda s: s.knn.n, y_dtype=jnp.int32)
 
     def lower_tick(self, ticks: int = 4):
         """Lower (but do NOT execute) a ``ticks``-long observe_many chunk.
@@ -283,6 +269,14 @@ class ServingEngine:
         active = jnp.ones((T, S), dtype=bool)
         return self._step_many.lower(state, xs, ys, taus,
                                      self._windows(state), active)
+
+    def lower_read(self, queries: int = 1):
+        """Lower (but do NOT execute) the read program, ``predict`` over
+        ``queries`` query points a tenant: the read's compiled form, as
+        ``lower_tick`` gives the tick's (a profile names the compiled
+        instructions; their ``op_name`` metadata holds the named scopes)."""
+        X = jnp.zeros((self.n_sessions, queries, self.dim), self.dtype)
+        return self._predict.lower(self.init_state(), X)
 
     def reset_occupancy(self) -> None:
         """Forget the host-side occupancy bound (grow mode) and the
@@ -326,13 +320,8 @@ class ServingEngine:
         if X_test.ndim == 2:
             X_test = jnp.broadcast_to(
                 X_test, (self.n_sessions,) + X_test.shape)
-        if self.telemetry is None:
-            return self._predict(state, X_test)
-        with self.telemetry.timed("predict",
-                                  signature=(X_test.shape, self.capacity),
-                                  tenants=self.n_sessions,
-                                  capacity=self.capacity) as tm:
-            return tm.sync(self._predict(state, X_test))
+        return engine_utils.dispatch_read(self, "predict", self._predict,
+                                          state, X_test)
 
     # -- snapshot -----------------------------------------------------------
 

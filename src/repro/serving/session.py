@@ -224,48 +224,53 @@ def _sliding_step(sess: Session, x_new, y_new, tau, window, active, *, k,
     n = knn.n
     act = jnp.asarray(active)
 
-    if evictable:
-        ev = act & (n >= window)
-        s = ev.astype(jnp.int32)
-        dcol = Dw[:, head]
-        head1 = _mod(head + s, wrap)
-        n1 = n - s
-        live1 = ring_live(w, head1, n1, wrap)
-        affected = (ev & (yw == yw[head]) & live1
-                    & (dcol <= bw[:, -1]))
-        cand = (yw[:, None] == yw[None, :]) & live1[None, :]
-        b1 = online.drop_backfill(bw, dcol, cand, Dw, affected, k=k)
-    else:
-        head1, n1, b1 = head, n, bw
+    # named scopes (op_name metadata only: same instructions, same
+    # bits) split the tick's device time by stage in a profile
+    with jax.named_scope("evict"):
+        if evictable:
+            ev = act & (n >= window)
+            s = ev.astype(jnp.int32)
+            dcol = Dw[:, head]
+            head1 = _mod(head + s, wrap)
+            n1 = n - s
+            live1 = ring_live(w, head1, n1, wrap)
+            affected = (ev & (yw == yw[head]) & live1
+                        & (dcol <= bw[:, -1]))
+            cand = (yw[:, None] == yw[None, :]) & live1[None, :]
+            b1 = online.drop_backfill(bw, dcol, cand, Dw, affected, k=k)
+        else:
+            head1, n1, b1 = head, n, bw
 
     # price + learn through the same code path as core.online.run_stream
-    knn1 = OnlineKnnState(Xw, yw, b1, n1)
-    knn2, p, d = online.observe_with_dists(knn1, x_new, y_new, tau, k=k,
-                                           head=head1, wrap=wrap)
+    with jax.named_scope("learn"):
+        knn1 = OnlineKnnState(Xw, yw, b1, n1)
+        knn2, p, d = online.observe_with_dists(knn1, x_new, y_new, tau, k=k,
+                                               head=head1, wrap=wrap)
 
-    # gate on ``active``: the big leaf (D) is written with its own
-    # current values on inactive lanes (D is symmetric, so the row at
-    # idx equals the column at idx); the small leaves are selects
-    idx = _mod(head1 + n1, wrap)
-    row = jnp.where(act, d, Dw[idx, :])
-    # bit-neutral scheduling marker: list entries are finite and >= 0
-    # and so is every value in ``row``, so ``+ b1[0,0] * 0.0`` adds +0.0
-    # exactly. It makes the in-place D update *depend* on the backfill
-    # reads of D — without the edge, XLA cannot prove the reads happen
-    # before the write and protects the donated (cap, cap) buffer with
-    # two full copies per tick (the O(cap^2) traffic this layout exists
-    # to remove; asserted gone by the HLO test)
-    row = row + b1[0, 0] * 0.0
-    D2 = sess.D.at[idx, :w].set(row).at[:w, idx].set(row)
-    knn3 = OnlineKnnState(
-        X=knn.X.at[:w].set(jnp.where(act, knn2.X, Xw)),
-        y=knn.y.at[:w].set(jnp.where(act, knn2.y, yw)),
-        best=knn.best.at[:w].set(jnp.where(act, knn2.best, b1)),
-        n=jnp.where(act, knn2.n, n1),
-    )
-    new_aid = _next_aid(aidw, head1, n1, wrap)
-    aid2 = sess.aid.at[idx].set(jnp.where(act, new_aid, sess.aid[idx]))
-    p = jnp.where(act, p, jnp.asarray(jnp.nan, dtype=Xw.dtype))
+    with jax.named_scope("write"):
+        # gate on ``active``: the big leaf (D) is written with its own
+        # current values on inactive lanes (D is symmetric, so the row at
+        # idx equals the column at idx); the small leaves are selects
+        idx = _mod(head1 + n1, wrap)
+        row = jnp.where(act, d, Dw[idx, :])
+        # bit-neutral scheduling marker: list entries are finite and >= 0
+        # and so is every value in ``row``, so ``+ b1[0,0] * 0.0`` adds +0.0
+        # exactly. It makes the in-place D update *depend* on the backfill
+        # reads of D — without the edge, XLA cannot prove the reads happen
+        # before the write and protects the donated (cap, cap) buffer with
+        # two full copies per tick (the O(cap^2) traffic this layout exists
+        # to remove; asserted gone by the HLO test)
+        row = row + b1[0, 0] * 0.0
+        D2 = sess.D.at[idx, :w].set(row).at[:w, idx].set(row)
+        knn3 = OnlineKnnState(
+            X=knn.X.at[:w].set(jnp.where(act, knn2.X, Xw)),
+            y=knn.y.at[:w].set(jnp.where(act, knn2.y, yw)),
+            best=knn.best.at[:w].set(jnp.where(act, knn2.best, b1)),
+            n=jnp.where(act, knn2.n, n1),
+        )
+        new_aid = _next_aid(aidw, head1, n1, wrap)
+        aid2 = sess.aid.at[idx].set(jnp.where(act, new_aid, sess.aid[idx]))
+        p = jnp.where(act, p, jnp.asarray(jnp.nan, dtype=Xw.dtype))
     return Session(knn3, D2, head1, aid2, wrap), p
 
 
@@ -453,26 +458,28 @@ def predict_pvalues(sess: Session, X_test, *, k, n_labels):
     cap = knn.X.shape[0]
     live = ring_live(cap, sess.head, knn.n, sess.wrap)
 
-    d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, knn.X), 0.0))  # (m, cap)
-    labels = jnp.arange(n_labels, dtype=knn.y.dtype)
-    same = (knn.y[None, :] == labels[:, None]) & live[None, :]  # (l, cap)
-    dm = jnp.where(same[None], d[:, None, :], BIG)  # (m, l, cap)
-    alpha = jnp.sum(-jax.lax.top_k(-dm, k)[0], axis=-1)  # (m, l)
+    with jax.named_scope("query"):  # d: (m, cap)
+        d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, knn.X), 0.0))
+        labels = jnp.arange(n_labels, dtype=knn.y.dtype)
+        same = (knn.y[None, :] == labels[:, None]) & live[None, :]  # (l, cap)
+        dm = jnp.where(same[None], d[:, None, :], BIG)  # (m, l, cap)
+        alpha = jnp.sum(-jax.lax.top_k(-dm, k)[0], axis=-1)  # (m, l)
 
-    kth = knn.best[:, -1]
-    full = live & (kth < BIG)  # k-best list fully populated
-    sum_same = jnp.where(full, jnp.sum(knn.best, axis=1), -BIG)
-    kth_same = jnp.where(full, kth, -BIG)
-    counts = kops.cp_knn_counts(
-        knn.X, jnp.where(live, knn.y, -1), sum_same, kth_same, X_test,
-        alpha, n_labels)
+    with jax.named_scope("count"):
+        kth = knn.best[:, -1]
+        full = live & (kth < BIG)  # k-best list fully populated
+        sum_same = jnp.where(full, jnp.sum(knn.best, axis=1), -BIG)
+        kth_same = jnp.where(full, kth, -BIG)
+        counts = kops.cp_knn_counts(
+            knn.X, jnp.where(live, knn.y, -1), sum_same, kth_same, X_test,
+            alpha, n_labels)
 
-    deficient = live & (kth >= BIG)
-    base = jnp.sum(knn.best[:, :-1], axis=1)  # (cap,)
-    upd = same[None] & (d[:, None, :] < kth)  # (m, l, cap)
-    scores = base + jnp.where(upd, d[:, None, :], kth)
-    ge = (scores >= alpha[..., None]) & deficient[None, None, :]
-    counts = counts + jnp.sum(ge.astype(counts.dtype), axis=-1)
+        deficient = live & (kth >= BIG)
+        base = jnp.sum(knn.best[:, :-1], axis=1)  # (cap,)
+        upd = same[None] & (d[:, None, :] < kth)  # (m, l, cap)
+        scores = base + jnp.where(upd, d[:, None, :], kth)
+        ge = (scores >= alpha[..., None]) & deficient[None, None, :]
+        counts = counts + jnp.sum(ge.astype(counts.dtype), axis=-1)
     return (counts + 1.0) / (knn.n + 1.0)
 
 

@@ -29,11 +29,9 @@ Per-tick stats (each reduced over the session axis):
 
     ticks          active lanes this tick
     evictions      active lanes at a full window (the decremental path
-                   runs; 0 by construction in grow mode)
+                   runs, with its one fused backfill reduction; 0 by
+                   construction in grow mode)
     ring_wraps     evictions whose head pointer rolls over to slot 0
-    backfills      exact-backfill reductions run (== evictions on both
-                   engines: every ring eviction repairs the k-NN lists
-                   with one fused reduction)
     occupancy_max  max post-tick live count over sessions
     occupancy_sum  sum of post-tick live counts (mean = sum / sessions)
 """
@@ -46,8 +44,8 @@ import jax.numpy as jnp
 
 # stats whose accumulation over ticks is a max, not a sum
 _MAX_KEYS = ("occupancy_max",)
-STAT_KEYS = ("ticks", "evictions", "ring_wraps", "backfills",
-             "occupancy_sum", "occupancy_max")
+STAT_KEYS = ("ticks", "evictions", "ring_wraps", "occupancy_sum",
+             "occupancy_max")
 _MAX_MASK_IDX = tuple(STAT_KEYS.index(k) for k in _MAX_KEYS)
 
 
@@ -85,7 +83,6 @@ def make_chunk_stats_fn(n_of: Callable, head_of: Callable,
             jnp.sum(act),        # ticks
             jnp.sum(ev),         # evictions
             jnp.sum(wraps),      # ring_wraps
-            jnp.sum(ev),         # backfills (== evictions)
             jnp.sum(n_after),    # occupancy_sum
             jnp.max(n_after),    # occupancy_max
         ])

@@ -112,15 +112,10 @@ class EngineTelemetry:
         routes its output through the yielded handle's ``sync()`` and
         this telemetry was built with ``sync=True``; see module doc)."""
         compile_flag = self.first_call(op, signature)
-        ann = contextlib.nullcontext()
-        if self.tracer is not None and self.tracer.annotate:
-            from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(f"repro.{op}")
-        with ann:
-            t0 = time.perf_counter()
-            handle = _TimedHandle(self.sync, t0)
-            yield handle
-            wall = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        handle = _TimedHandle(self.sync, t0)
+        yield handle
+        wall = time.perf_counter() - t0
         self.record_op(op, wall, compile_flag=compile_flag, ticks=ticks,
                        tenants=tenants, capacity=capacity,
                        dispatch_s=handle.late.get("dispatch_s"))

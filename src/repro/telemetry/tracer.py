@@ -55,9 +55,9 @@ optional, schema v3 (fault schedule — robustness.faults)
     extra: any remaining keys are recorder-specific (e.g. drained device
     counters on a flush record) and must be JSON-serializable.
 
-``Tracer(path, annotate=True)`` additionally wraps each recorded op in a
-``jax.profiler.TraceAnnotation`` so records line up with device traces
-captured via ``jax.profiler.trace()``.
+The engines write their own ``repro.<op>`` spans into a profile being
+captured (``core.engine_utils.DispatchSpans``), so a record's op lines up
+with a device trace taken through ``jax.profiler.trace()`` by name.
 """
 from __future__ import annotations
 
@@ -207,13 +207,10 @@ class Tracer:
     """Append-only JSONL trace writer.
 
     Records are flushed per line (the file is valid mid-run; a crash
-    loses at most the current line). ``annotate=True`` wraps ``op()``
-    bodies in ``jax.profiler.TraceAnnotation(op)`` so host records can
-    be joined against an XLA profiler trace of the same run.
+    loses at most the current line).
     """
 
-    def __init__(self, path_or_file: str | IO[str], *,
-                 annotate: bool = False):
+    def __init__(self, path_or_file: str | IO[str]):
         if isinstance(path_or_file, str):
             d = os.path.dirname(path_or_file)
             if d:
@@ -225,7 +222,6 @@ class Tracer:
             self._f = path_or_file
             self._owns = False
             self.path = getattr(path_or_file, "name", None)
-        self.annotate = annotate
         self._t0 = time.perf_counter()
         self._seq = 0
         self._seen: set = set()
@@ -304,23 +300,16 @@ class _OpContext:
         self._op = op
         self._sig = signature
         self._fields = dict(fields)
-        self._ann = None
         self.late: dict[str, Any] = {}
 
     def __enter__(self):
         self._fields.setdefault(
             "compile", self._tracer.first_call(self._op, self._sig))
-        if self._tracer.annotate:
-            from jax.profiler import TraceAnnotation
-            self._ann = TraceAnnotation(f"repro.{self._op}")
-            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         wall = time.perf_counter() - self._t0
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         if exc[0] is None:
             self._tracer.record(self._op, wall,
                                 **{**self._fields, **self.late})

@@ -8,7 +8,10 @@ The acceptance-critical properties:
   traffic (closed form == per-tick simulation);
 * the rolling coverage monitor matches an exact offline recomputation,
   and the drift monitor matches ``core.online``'s mixture martingale;
-* ``launch/serve.py --trace-out`` produces a schema-valid trace.
+* ``launch/serve.py --trace-out`` produces a schema-valid trace;
+* the tick and read programs carry their stage scopes in their op names,
+  and every engine dispatch writes its ``repro.*`` spans into a profile,
+  instrumented or not.
 """
 import io
 import json
@@ -269,7 +272,6 @@ def _simulate_stats(n0, head0, wrap, windows, actives):
         tot["ticks"] += int(act.sum())
         tot["evictions"] += int(ev.sum())
         tot["ring_wraps"] += int((ev & (head == wrap - 1)).sum())
-        tot["backfills"] += int(ev.sum())
         head = np.where(ev, (head + 1) % wrap, head)
         n = np.where(act, np.minimum(n + 1, windows), n)
         tot["occupancy_sum"] += int(n.sum())
@@ -311,6 +313,130 @@ def test_device_tick_stats_match_offline_simulation():
     # drained: a second drain is empty and totals persist
     assert eng.telemetry.drain() == {k: 0 for k in STAT_KEYS}
     assert eng.telemetry.ticks.totals["evictions"] == total["evictions"]
+
+
+# ---------------------------------- the programs' own scopes and spans
+
+
+def _op_names(compiled_text):
+    import re
+    return set(re.findall(r'op_name="([^"]*)"', compiled_text))
+
+
+def _under(names, scope, inside=""):
+    """Some op's name path holds ``scope`` (bare, or opened under a
+    vmap) after ``inside``."""
+    parts = (f"/{scope}/", f"/vmap({scope})/")
+    return any(inside in n and any(p in n[n.find(inside):] for p in parts)
+               for n in names)
+
+
+def _tiny_engine(kind, **kw):
+    from repro.regression import RegressionServingEngine
+    from repro.serving import ServingEngine
+
+    if kind == "class":
+        return ServingEngine(n_sessions=3, capacity=16, dim=3, k=3,
+                             n_labels=2, window=12, **kw)
+    return RegressionServingEngine(n_sessions=3, capacity=16, dim=3, k=3,
+                                   window=12, **kw)
+
+
+@pytest.mark.parametrize("kind", ["class", "reg"])
+@pytest.mark.parametrize("scope", ["evict", "learn", "write"])
+def test_tick_program_carries_stage_scopes(kind, scope):
+    eng = _tiny_engine(kind)
+    names = _op_names(eng.lower_tick(4).compile().as_text())
+    assert _under(names, scope, inside="/while/body/"), sorted(names)[:20]
+
+
+@pytest.mark.parametrize("kind", ["class", "reg"])
+def test_instrumented_tick_stats_run_under_stats_scope(kind):
+    eng = _tiny_engine(kind, instrument=True, metrics=MetricsRegistry())
+    names = _op_names(eng.lower_tick(4).compile().as_text())
+    assert _under(names, "stats")
+    # the stats run once per chunk, outside the scanned tick body
+    assert not _under(names, "stats", inside="/while/body/")
+
+
+@pytest.mark.parametrize("route", ["jnp", "pallas"])
+@pytest.mark.parametrize("kind", ["class", "reg"])
+def test_read_program_carries_stage_scopes(kind, route, monkeypatch):
+    if route == "pallas":  # the kernels' route, through interpret mode
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    jax.clear_caches()
+    stages = (("query", "count") if kind == "class"
+              else ("gather", "query", "sweep", "hull"))
+    names = _op_names(_tiny_engine(kind).lower_read(2).compile().as_text())
+    jax.clear_caches()
+    for stage in stages:
+        assert _under(names, stage), (stage, sorted(names)[:20])
+
+
+def _profile_spans(run, tmp_path):
+    """The ``repro.*`` host spans ``run()`` writes into a profile:
+    (name, start, end, seq)."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats).get("seq")) for e in line.events
+                       if e.name.startswith("repro."))
+    return out
+
+
+def _children(spans, outer):
+    name, s, e, seq = outer
+    return sorted(n for n, s1, e1, q in spans
+                  if q == seq and (n, s1, e1) != (name, s, e)
+                  and s <= s1 and e1 <= e)
+
+
+@pytest.mark.parametrize("instrument", [False, True],
+                         ids=["plain", "instrumented"])
+def test_engines_write_dispatch_spans(instrument, tmp_path):
+    kw = dict(instrument=True, metrics=MetricsRegistry()) if instrument \
+        else {}
+    cls, reg = _tiny_engine("class", **kw), _tiny_engine("reg", **kw)
+    xs, ys, taus = _class_traffic(3, 4, 3, seed=1)
+    sc, sr = cls.init_state(), reg.init_state()
+    Xq = jnp.zeros((3, 2, 3), jnp.float32)
+
+    def serve(sc, sr):
+        sc, _ = cls.observe_many(sc, xs, ys, taus)
+        sr, _ = reg.observe_many(sr, xs, ys.astype(jnp.float32), taus)
+        jax.block_until_ready((cls.predict(sc, Xq),
+                               reg.intervals(sr, Xq, 0.1)))
+        return sc, sr
+
+    sc, sr = serve(sc, sr)  # compile outside the profile
+    spans = _profile_spans(lambda: serve(sc, sr), tmp_path)
+    outer = [sp for sp in spans if sp[0] in (
+        "repro.observe_many", "repro.predict", "repro.intervals")]
+    assert sorted(sp[0] for sp in outer) == [
+        "repro.intervals", "repro.observe_many", "repro.observe_many",
+        "repro.predict"]
+    # every span of a dispatch carries its sequence number
+    assert all(isinstance(sp[3], int) for sp in spans)
+    for sp in outer:
+        if sp[0] == "repro.observe_many":
+            want = ["repro.launch", "repro.prepare"]
+            if instrument:
+                want = ["repro.fold"] + want
+        else:
+            want = ["repro.launch"]
+        assert _children(spans, sp) == want, sp
+    # each engine numbers its own dispatches, two each before the profile
+    seqs = [sp[3] for sp in sorted(outer, key=lambda sp: sp[1])]
+    assert seqs == [3, 3, 4, 4]
 
 
 def test_engine_telemetry_without_accessors_is_timing_only():
