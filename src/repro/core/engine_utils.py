@@ -28,7 +28,7 @@ def scan_chunk(vstep, stats_fn=None):
     One jitted dispatch advances T ticks; the leading-axis chunk length
     is the only retrace axis (the scan is rolled). Donating the carry at
     the jit boundary makes every per-tick (cap, cap) row/column insert
-    an in-place dynamic-update-slice.
+    an in-place update.
 
     ``stats_fn`` (optional — the telemetry hook, built by
     ``telemetry.device.make_chunk_stats_fn``) is evaluated ONCE per

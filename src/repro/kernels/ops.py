@@ -136,6 +136,18 @@ def stream_update(X, y, nbr_d, nbr_y, x_new, y_new, n, *, mode, head=None,
                                    mode=mode, head=head, wrap=wrap)
 
 
+def dist_insert(D, row, idx):
+    """Row and column ``idx`` of the distance matrix ``D`` (cap, cap) set
+    to ``row`` (w,) over its leading (w, w) block; on TPU the column is
+    the Pallas lane-strip insert, which keeps ``D`` row-major (callable
+    under ``vmap``: one kernel call covers every tenant)."""
+    if pallas_active(D.dtype):
+        from repro.kernels.dist_insert import dist_insert as _pallas
+
+        return _pallas(D, row, idx, interpret=not _on_tpu())
+    return _ref.dist_insert(D, row, idx)
+
+
 def _pow2(v: int, lo: int = 8) -> int:
     n = lo
     while n < v:

@@ -153,8 +153,8 @@ def stream_update(
       carry the row's own label.
 
     The caller keeps the new row's own top-k list, the D row/column
-    scatter (an O(cap) in-place dynamic-update-slice under donation) and
-    the p-value — none of which belong in a tiled kernel. Returns
+    write (``dist_insert``, in place under donation) and the p-value —
+    none of which belong in a tiled kernel. Returns
     ``(d_row (cap,), nbr_d' (cap, k), nbr_y' (cap, k))``. Semantics of
     record for the Pallas kernel in ``stream_update.py``; expressions
     mirror ``core.online._observe_impl`` / ``regression.stream.observe``
@@ -248,6 +248,13 @@ def stream_update_fast(
                                jnp.asarray(y_new, nbr_y.dtype), Ysh))
     newY = jnp.where(newL >= _BIG, y[:, None], newY)
     return d_row, newL, newY
+
+
+def dist_insert(D: jnp.ndarray, row: jnp.ndarray, idx) -> jnp.ndarray:
+    """Write ``row`` (w,) into row ``idx`` and column ``idx`` of the
+    leading (w, w) block of the distance matrix ``D`` (cap, cap)."""
+    w = row.shape[-1]
+    return D.at[idx, :w].set(row).at[:w, idx].set(row)
 
 
 def boot_fit_tree(X, y, w, feat_choice, thr_u, n_labels, depth):

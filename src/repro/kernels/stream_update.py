@@ -20,13 +20,10 @@ Stays with the caller (none of it belongs in a tiled kernel):
 
 * the new row's *own* k-best list — a top_k over the emitted distance
   row;
-* the scatter of the distance row into the maintained pairwise matrix
-  ``D``'s row idx and column idx. ``D`` cannot be aliased through
-  ``pallas_call`` without tile-aligning (i.e. copying) the whole
-  (cap, cap) buffer, which is exactly the O(cap^2) traffic this change
-  removes — instead the caller's two ``.at[idx].set`` updates lower to
-  in-place dynamic-update-slices once the jitted step donates its input
-  state (``donate_argnums``), which is O(cap) HBM traffic;
+* the write of the distance row into the maintained pairwise matrix
+  ``D``'s row idx and column idx (``ops.dist_insert``: a row scatter
+  and the lane-strip kernel of ``dist_insert.py``, both in place once
+  the jitted step donates its input state);
 * the smoothed p-value (an O(cap) reduction over pre-update scores).
 
 ``kernels/ref.py::stream_update`` is the semantics of record; the

@@ -158,7 +158,7 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
         if evictable:
             ev = act & (n >= window)
             s = ev.astype(jnp.int32)
-            dcol = Dw[:, head]
+            dcol = Dw[head, :]  # D is bitwise symmetric: the column as a row
             head1 = _mod_cap(head + s, wrap)
             n1 = n - s
             live1 = ring_live(w, head1, n1, wrap)
@@ -199,14 +199,14 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
     with jax.named_scope("write"):
         row = jnp.where(act, d_row, Dw[idx, :])  # D symmetric: row == col
         # bit-neutral scheduling marker (see
-        # serving.session._sliding_step): the in-place D update must
-        # depend on every repaired list (each carries backfill reads of
-        # D) or XLA copies the donated (cap, cap) buffer twice per tick.
-        # Distances are finite and >= 0 and labels and ids finite, so the
-        # term is exactly +0.0
+        # serving.session._sliding_step): the D insert depends on every
+        # repaired list (each carries backfill reads of D), which keeps
+        # the XLA scatter route (CPU) to one copy of D fewer. Distances
+        # are finite and >= 0 and labels and ids finite, so the term is
+        # exactly +0.0
         row = row + (nbr_d1[0, 0]
                      + (nbr_y1[0, 0] + nbr_a1[0, 0]) * 0.0) * 0.0
-        D2 = state.D.at[idx, :w].set(row).at[:w, idx].set(row)
+        D2 = kops.dist_insert(state.D, row, idx)
         new_state = RegStreamState(
             X=state.X.at[idx].set(jnp.where(act, x_new, Xw[idx])),
             y=state.y.at[idx].set(jnp.where(act, y_new, yw[idx])),

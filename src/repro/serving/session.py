@@ -230,7 +230,10 @@ def _sliding_step(sess: Session, x_new, y_new, tau, window, active, *, k,
         if evictable:
             ev = act & (n >= window)
             s = ev.astype(jnp.int32)
-            dcol = Dw[:, head]
+            # the head's column, read as its row: every write sets row
+            # and column idx from one vector, so D is bitwise symmetric,
+            # and a row read keeps D row-major
+            dcol = Dw[head, :]
             head1 = _mod(head + s, wrap)
             n1 = n - s
             live1 = ring_live(w, head1, n1, wrap)
@@ -255,13 +258,14 @@ def _sliding_step(sess: Session, x_new, y_new, tau, window, active, *, k,
         row = jnp.where(act, d, Dw[idx, :])
         # bit-neutral scheduling marker: list entries are finite and >= 0
         # and so is every value in ``row``, so ``+ b1[0,0] * 0.0`` adds +0.0
-        # exactly. It makes the in-place D update *depend* on the backfill
-        # reads of D — without the edge, XLA cannot prove the reads happen
-        # before the write and protects the donated (cap, cap) buffer with
-        # two full copies per tick (the O(cap^2) traffic this layout exists
-        # to remove; asserted gone by the HLO test)
+        # exactly. It makes the D insert *depend* on the backfill reads of
+        # D. Through the TPU kernel the compiled chunk holds no full copy
+        # of D with or without it; on the XLA scatter route (CPU) dropping
+        # it adds one more full copy of the donated (cap, cap) buffer
         row = row + b1[0, 0] * 0.0
-        D2 = sess.D.at[idx, :w].set(row).at[:w, idx].set(row)
+        # row and column idx in place; on TPU the column is a lane-strip
+        # kernel, so D stays row-major through the scanned chunk
+        D2 = kops.dist_insert(sess.D, row, idx)
         knn3 = OnlineKnnState(
             X=knn.X.at[:w].set(jnp.where(act, knn2.X, Xw)),
             y=knn.y.at[:w].set(jnp.where(act, knn2.y, yw)),

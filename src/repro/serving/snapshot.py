@@ -295,9 +295,16 @@ class AsyncShardedSaver:
         self._check_err()
         S = jax.tree_util.tree_leaves(state)[0].shape[0]
         cuts = [S * i // self.shards for i in range(self.shards + 1)]
-        # device-side slicing: new buffers per shard, donation-safe
+        # device-side slicing: new buffers per shard, donation-safe. A
+        # slice that spans the whole leaf is the leaf itself, which the
+        # next donating tick deletes under the worker: copy that one
+        def cut(leaf, a, b):
+            part = leaf[a:b]
+            return jnp.copy(part) if part is leaf else part
+
         slices = [
-            jax.tree_util.tree_map(lambda l: l[cuts[i]:cuts[i + 1]], state)
+            jax.tree_util.tree_map(
+                lambda l: cut(l, cuts[i], cuts[i + 1]), state)
             for i in range(self.shards)]
         self._q.put((step, slices, meta))
 

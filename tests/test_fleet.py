@@ -189,6 +189,45 @@ def test_async_saver_surfaces_worker_errors(tmp_path):
         saver.close()
 
 
+def test_async_saver_one_shard_survives_donated_state(tmp_path):
+    """With one shard the slice spans the whole state; a donating tick
+    right after ``save`` deletes the caller's buffers before the worker
+    pulls them, and the snapshot must still hold the saved values."""
+    import threading
+
+    import jax
+
+    release = threading.Event()
+
+    class Held(SessionStore):  # the worker sits in step 0's commit
+        def save(self, step, *a, **kw):
+            if step == 0:
+                release.wait(timeout=30)
+            return super().save(step, *a, **kw)
+
+    eng = ServingEngine(n_sessions=4, capacity=8, dim=D, k=K,
+                        n_labels=2, window=None)
+    store = Held(str(tmp_path))
+    saver = AsyncShardedSaver(store, shards=1)
+    saver.save(0, eng.init_state(), meta=eng.meta())
+    rng = np.random.default_rng(5)
+    state, _ = eng.observe_many(
+        eng.init_state(),
+        jnp.asarray(rng.normal(size=(3, 4, D)), jnp.float32),
+        jnp.asarray(rng.integers(0, 2, size=(3, 4)), jnp.int32),
+        jnp.asarray(rng.uniform(size=(3, 4)), jnp.float32))
+    want = [np.asarray(a) for a in jax.tree_util.tree_leaves(state)]
+    saver.save(1, state, meta=eng.meta())
+    for leaf in jax.tree_util.tree_leaves(state):
+        leaf.delete()  # what donating it to the next tick does
+    release.set()
+    saver.close()
+    _, got, step = store.restore_engine()
+    assert step == 1
+    for a, b in zip(want, jax.tree_util.tree_leaves(got)):
+        np.testing.assert_array_equal(a, np.asarray(b))
+
+
 _SHARDED_FLEET = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -8,6 +8,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.cp_update import cp_knn_counts as cp_pallas
+from repro.kernels.dist_insert import dist_insert as di_pallas
 from repro.kernels.interval_sweep import interval_sweep as iv_pallas
 from repro.kernels.kde_score import kde_rowsums as kde_pallas
 from repro.kernels.pairwise_dist import pairwise_sq_dists
@@ -241,6 +242,37 @@ def test_chunked_attention_matches_dense(Sq, Skv, window):
     want = ref.flash_attention(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("cap,w,idx", [
+    (64, 64, (0, 63, 17)),        # one strip: the whole row
+    (64, 40, (0, 39, 5)),         # window block inside the capacity
+    (1024, 1024, (3, 1020, 128)),  # first and last lane block, a boundary
+    (1024, 600, (127, 599, 256)),  # w < cap across lane blocks
+    (200, 200, (0, 199, 128)),    # 128 does not divide cap: the last
+    (200, 150, (127, 149, 130)),  # strip overhangs the row
+    (215, 215, (214, 3, 200)),    # ... and rows not a multiple of 8
+])
+def test_dist_insert_matches_ref(cap, w, idx):
+    """The lane-strip column insert is bit-equal to the two scatters, on
+    a stack of random symmetric D under vmap (one kernel call) and on one
+    D alone; a lane whose row is D's own row (an inactive tenant) keeps
+    D bitwise unchanged."""
+    B = len(idx)
+    A = jax.random.uniform(jax.random.PRNGKey(cap + w), (B, cap, cap),
+                           jnp.float32)
+    D = A + jnp.swapaxes(A, 1, 2)  # symmetric, bitwise
+    idx = jnp.asarray(idx, jnp.int32)
+    row = jax.random.uniform(jax.random.PRNGKey(w), (B, w), jnp.float32)
+    row = row.at[B - 1].set(D[B - 1, idx[B - 1], :w])  # inactive lane
+    want = jax.vmap(ref.dist_insert)(D, row, idx)  # the two scatters
+    got = jax.vmap(lambda d, r, i: di_pallas(d, r, i, interpret=True))(
+        D, row, idx)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[B - 1]),
+                                  np.asarray(D[B - 1]))
+    one = di_pallas(D[0], row[0], idx[0], interpret=True)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(want[0]))
 
 
 def test_ops_dispatch_interpret(monkeypatch):

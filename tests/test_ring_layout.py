@@ -358,6 +358,35 @@ def test_legacy_linear_snapshot_restores_and_serves():
         _assert_trees_equal(a, b)
 
 
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+@pytest.mark.parametrize("kind", ["class", "reg"])
+def test_engine_distance_block_bitwise_symmetric(kind, route, monkeypatch):
+    """After 3 windows of evicting ticks (the ring wrapped, some lanes
+    inactive) every tenant's D equals its transpose bit for bit: the
+    invariant that lets the eviction read the head's column as its row.
+    ``kernel`` runs the tick's Pallas kernels in interpret mode."""
+    if route == "kernel":
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    S, cap, w, k = 2, 16, 8, 3
+    T = 3 * w
+    if kind == "class":
+        eng = ServingEngine(n_sessions=S, capacity=cap, dim=DIM, k=k,
+                            n_labels=2, window=w)
+        streams = [_class_stream(T, seed=800 + s) for s in range(S)]
+    else:
+        eng = RegressionServingEngine(n_sessions=S, capacity=cap, dim=DIM,
+                                      k=k, window=w)
+        streams = [_reg_stream(T, seed=850 + s) for s in range(S)]
+    xs, ys, taus = (jnp.stack([jnp.stack([st_[i][t] for st_ in streams])
+                               for t in range(T)]) for i in range(3))
+    active = (jnp.arange(T)[:, None] + jnp.arange(S)[None, :]) % 5 != 0
+    state, _ = eng.observe_many(eng.init_state(), xs, ys, taus,
+                                active=active)
+    assert int(jnp.min(state.head)) > 0  # every ring wrapped
+    D = np.asarray(state.D)
+    np.testing.assert_array_equal(D, np.swapaxes(D, 1, 2))
+
+
 def test_engine_rejects_mismatched_ring_modulus():
     eng = ServingEngine(n_sessions=1, capacity=16, dim=DIM, k=3,
                         n_labels=2, window=8)

@@ -13,6 +13,8 @@ change without a chip. Each compiled program must hold the kernel as a
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -110,18 +112,18 @@ def test_kde_score_compiles(one_chip):
                    s((M,), jnp.int32), s((CAP,), jnp.int32), h=1.0)
 
 
-@pytest.mark.parametrize("family", ["classification", "regression"])
-def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
-    """One served ``observe_many`` chunk at 256 tenants x window 1024:
-    the observe kernel is inside, and the donated state aliases."""
+def _compile_chunk(one_chip, family, cap):
+    """One served ``observe_many`` chunk of ``family``'s engine at
+    TENANTS x window ``cap``, compiled for one v5e chip; with its state's
+    shapes."""
     if family == "classification":
         from repro.serving import ServingEngine as Engine
         extra, ydt = dict(n_labels=2), jnp.int32
     else:
         from repro.regression import RegressionServingEngine as Engine
         extra, ydt = {}, jnp.float32
-    eng = Engine(n_sessions=TENANTS, capacity=CAP, dim=DIM, k=K,
-                 window=CAP, instrument=True, **extra)
+    eng = Engine(n_sessions=TENANTS, capacity=cap, dim=DIM, k=K,
+                 window=cap, instrument=True, **extra)
     state = jax.tree_util.tree_map(
         lambda a: _spec(one_chip, a.shape, a.dtype),
         jax.eval_shape(eng.init_state))
@@ -130,6 +132,14 @@ def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
         state, s((CHUNK, TENANTS, DIM)), s((CHUNK, TENANTS), ydt),
         s((CHUNK, TENANTS)), s((TENANTS,), jnp.int32),
         s((CHUNK, TENANTS), jnp.bool_)).compile()
+    return compiled, state
+
+
+@pytest.mark.parametrize("family", ["classification", "regression"])
+def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
+    """One served ``observe_many`` chunk at 256 tenants x window 1024:
+    the observe kernel is inside, and the donated state aliases."""
+    compiled, state = _compile_chunk(one_chip, family, CAP)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     state_bytes = sum(a.size * a.dtype.itemsize
@@ -137,3 +147,36 @@ def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
     assert mem.alias_size_in_bytes >= state_bytes  # donation holds
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 2 ** 30)  # fits one v5e chip's HBM
+
+
+@pytest.mark.parametrize("family,cap", [("classification", 1024),
+                                        ("regression", 512),
+                                        ("classification", 200)])
+def test_engine_chunk_keeps_distance_block_row_major(one_chip, tpu_route,
+                                                     family, cap):
+    """The compiled chunk's ticks never re-lay the (S, cap, cap) distance
+    block: no copy or transpose whose result is the whole block inside
+    the scanned body, and no column-major layout of it anywhere (the
+    column insert is the ``dist_insert`` kernel, in place). At the
+    benchmark cells' sizes the chunk copies the block nowhere. Where 128
+    does not divide cap, the chip's default layout of the argument is
+    not row-major ({0,2,1} at 200), so the chunk converts it once on
+    entry and once on exit, and no more."""
+    compiled, _ = _compile_chunk(one_chip, family, cap)
+    text = compiled.as_text()
+    block = re.escape(f"f32[{TENANTS},{cap},{cap}]")
+    relaid = {"entry": [], "body": []}
+    part = None
+    for line in text.splitlines():
+        if line.startswith("ENTRY"):
+            part = "entry"
+        elif line.startswith("%"):  # any other computation
+            part = "body"
+        m = re.search(rf"%\S+ = {block}\{{[^}}]*\}} (?:copy|transpose)\(",
+                      line)
+        if m:
+            relaid[part].append(m.group(0))
+    assert not relaid["body"], relaid
+    assert len(relaid["entry"]) == (0 if cap % 128 == 0 else 2), relaid
+    assert not re.search(rf"{block}\{{1,2,0", text)
+    assert "dist_insert" in text
