@@ -353,7 +353,7 @@ def check_retrace(target: AuditTarget, art: Artifact) -> dict:
     def caches():
         read = (eng._predict if t.family == "classification"
                 else eng._intervals)
-        return {"step": eng._step_many._cache_size(),
+        return {"step": len(eng._chunks),
                 "read": read._cache_size()}
 
     state = lifecycle(eng.init_state())

@@ -81,12 +81,41 @@ class DispatchSpans:
             yield lambda phase: TraceAnnotation(f"repro.{phase}", seq=seq)
 
 
+class ChunkPrograms:
+    """An engine's chunk executables. Called with a chunk's arguments,
+    it returns the jitted chunk ``step`` compiled ahead of time for
+    their shapes and dtypes, on their first appearance, and reused
+    after. Every chunk dispatch calls one of these, so what runs is an
+    object that can be asked for its own memory
+    (``memory_analysis()``)."""
+
+    def __init__(self, step):
+        self.step = step
+        self._exes: dict = {}
+
+    def __len__(self) -> int:
+        return len(self._exes)
+
+    def __call__(self, args):
+        key = tuple((a.shape, a.dtype)
+                    for a in jax.tree_util.tree_leaves(args))
+        exe = self._exes.get(key)
+        if exe is None:
+            exe = self._exes[key] = self.step.lower(*args).compile()
+        return exe
+
+
 def dispatch_chunk(eng, state, xs, ys, taus, active, *, op: str, n_of,
                    y_dtype):
     """The engines' shared observe/observe_many dispatch, under the host
     spans ``repro.<op>`` > ``prepare`` (room, invariants, the chunk's
-    arguments), ``launch`` (the jitted chunk) and, when instrumented,
-    ``fold`` (the tick stats into their accumulator)."""
+    arguments), ``place`` (with ``shards > 1`` only: the arguments laid
+    out on the tenant mesh), ``launch`` (the chunk program) and, when
+    instrumented, ``fold`` (the tick stats into their accumulator).
+
+    The chunk runs as the engine's ``ChunkPrograms`` executable for the
+    arguments' shapes, so an instrumented engine's telemetry reads the
+    byte counts of the very program it dispatches."""
     with eng._spans(op) as span:
         with span("prepare"):
             if active is None:
@@ -95,15 +124,21 @@ def dispatch_chunk(eng, state, xs, ys, taus, active, *, op: str, n_of,
             check_window_occupancy(eng, state, n_of, lambda s: s.wrap)
             args = (state, xs, ys.astype(y_dtype), taus.astype(eng.dtype),
                     eng._windows(state), active)
+        if eng._mesh is not None:
+            from repro.core import distributed as dist
+            with span("place"):
+                args = dist.place_chunk_args(args, eng._mesh)
         if eng.telemetry is None:
             with span("launch"):
-                return eng._step_many(*args)
+                return eng._chunks(args)(*args)
         T, S = xs.shape[:2]
         with eng.telemetry.timed(op, signature=(xs.shape, eng.capacity),
                                  ticks=T, tenants=S,
                                  capacity=eng.capacity) as tm:
             with span("launch"):
-                state, (p, stats) = eng._step_many(*args)
+                run = eng._chunks(args)
+                eng.telemetry.note_program(run, args[0], shards=eng.shards)
+                state, (p, stats) = run(*args)
             tm.sync(p)
         with span("fold"):
             eng.telemetry.ticks.fold(stats)

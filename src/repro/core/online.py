@@ -99,6 +99,19 @@ def next_aid(aid, head, n, wrap):
     return jnp.where(n > 0, aid[newest] + 1, 0)
 
 
+def slot_set(a, idx, v, when=True):
+    """``a.at[idx].set(v)`` for one slot ``idx`` of the leading (slot)
+    axis, as a select (left unchanged where ``when`` is False): the
+    same bits, and under the engines' ``vmap`` an elementwise pass in
+    whatever layout the leaf has. A per-tenant scatter into a (tenants,
+    cap, m) leaf that the chip keeps with the window minor re-tiles the
+    whole leaf twice a tick; the select fuses with the tick's gating
+    select over the same leaf."""
+    hit = (jnp.arange(a.shape[0]) == idx) & when
+    return jnp.where(hit.reshape((-1,) + (1,) * (a.ndim - 1)),
+                     jnp.asarray(v, a.dtype), a)
+
+
 def cshift(a, s, fill):
     """Conditionally drop the leading row: shift rows up by ``s`` (a
     traced 0/1 scalar) with ``fill`` entering at the tail — one padded
@@ -350,9 +363,9 @@ def _observe_impl(state: OnlineKnnState, x_new, y_new, tau, *, k,
     # list is the k best same-label distances seen so far
     own = jnp.sort(-jax.lax.top_k(-cand, k)[0])
     new_state = OnlineKnnState(
-        X=state.X.at[idx].set(x_new),
-        y=state.y.at[idx].set(y_new.astype(state.y.dtype)),
-        best=merged.at[idx].set(own),
+        X=slot_set(state.X, idx, x_new),
+        y=slot_set(state.y, idx, y_new),
+        best=slot_set(merged, idx, own),
         n=state.n + 1,
     )
     return new_state, p, d
@@ -400,5 +413,5 @@ def run_stream(X, y, *, k, key, capacity=None):
 __all__ = ["OnlineKnnState", "init", "observe", "observe_with_dists",
            "run_stream", "power_martingale_increment",
            "simple_mixture_log_martingale", "ring_age", "ring_live",
-           "ring_slots", "cshift", "drop_backfill", "drop_backfill_core",
-           "BIG"]
+           "ring_slots", "slot_set", "cshift", "drop_backfill",
+           "drop_backfill_core", "BIG"]

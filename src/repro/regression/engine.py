@@ -161,6 +161,7 @@ class RegressionServingEngine:
                                          (True, True, False))
         self._step_many = jax.jit(
             chunk, donate_argnums=(0,) if donate else ())
+        self._chunks = engine_utils.ChunkPrograms(self._step_many)
         self._pvalues = jax.jit(pvals)
         self._intervals = jax.jit(ivals)
         self._n_bound: int | None = None
@@ -173,13 +174,21 @@ class RegressionServingEngine:
 
         Sliding engines confine every session's ring to the
         ``[:window]`` leaf block (``wrap == wmax``); grow mode uses the
-        full capacity as the modulus (the ring never wraps there)."""
-        one = sess_m.init(self.capacity, self.dim, self.k,
-                          dtype=self.dtype, wrap=self._wmax)
-        state = jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (self.n_sessions,) + a.shape),
-            one)
-        return self._shard_state(state)
+        full capacity as the modulus (the ring never wraps there).
+        With ``shards > 1`` every leaf is made with a tenant-sharded
+        NamedSharding across the mesh, each device filling only its own
+        tenants' slice."""
+        def build():
+            one = sess_m.init(self.capacity, self.dim, self.k,
+                              dtype=self.dtype, wrap=self._wmax)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.broadcast_to(a, (self.n_sessions,) + a.shape),
+                one)
+
+        if self._mesh is None:
+            return build()
+        from repro.core import distributed as dist
+        return dist.init_tenant_sharded(build, self._mesh)
 
     def _shard_state(self, state: RegStreamState) -> RegStreamState:
         """Lay the stacked state out tenant-sharded across the mesh."""

@@ -42,7 +42,7 @@ from repro.kernels import ops as kops
 from repro.regression import stream
 from repro.regression.stream import RegStreamState, _mod_cap, _next_aid
 from repro.core.online import (cshift, drop_backfill, ring_age, ring_live,
-                               ring_slots)
+                               ring_slots, slot_set)
 
 init = stream.init
 
@@ -181,7 +181,7 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
         d_row, nbr_d_m, nbr_y_m = kops.stream_update(
             Xw, yw, nbr_d1, nbr_y1, x_new, y_new, n1, mode="reg",
             head=head1, wrap=wrap)
-        y2w = yw.at[idx].set(jnp.where(act, y_new, yw[idx]))
+        y2w = slot_set(yw, idx, y_new, act)
         sub = RegStreamState(Xw, yw, Dw, nbr_d1, nbr_y1, n1, head1, aidw,
                              wrap, nbr_a1)
         own_d, own_y, y_sel, own_a = stream._own_list(sub, d_row, y2w,
@@ -208,20 +208,20 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
                      + (nbr_y1[0, 0] + nbr_a1[0, 0]) * 0.0) * 0.0
         D2 = kops.dist_insert(state.D, row, idx)
         new_state = RegStreamState(
-            X=state.X.at[idx].set(jnp.where(act, x_new, Xw[idx])),
-            y=state.y.at[idx].set(jnp.where(act, y_new, yw[idx])),
+            X=slot_set(state.X, idx, x_new, act),
+            y=slot_set(state.y, idx, y_new, act),
             D=D2,
             nbr_d=state.nbr_d.at[:w].set(
-                jnp.where(act, nbr_d_m.at[idx].set(own_d), nbr_d1)),
+                jnp.where(act, slot_set(nbr_d_m, idx, own_d), nbr_d1)),
             nbr_y=state.nbr_y.at[:w].set(
-                jnp.where(act, nbr_y_m.at[idx].set(own_y), nbr_y1)),
+                jnp.where(act, slot_set(nbr_y_m, idx, own_y), nbr_y1)),
             n=n1 + act,
             head=head1,
             aid=state.aid.at[idx].set(
                 jnp.where(act, new_aid, state.aid[idx])),
             wrap=wrap,
             nbr_a=state.nbr_a.at[:w].set(
-                jnp.where(act, nbr_a_m.at[idx].set(own_a), nbr_a1)),
+                jnp.where(act, slot_set(nbr_a_m, idx, own_a), nbr_a1)),
         )
         p = jnp.where(act, p, jnp.asarray(jnp.nan, dtype=Xw.dtype))
     return new_state, p

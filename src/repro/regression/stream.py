@@ -53,7 +53,7 @@ import jax.numpy as jnp
 
 from repro.core.online import (drop_backfill, next_aid as _next_aid,
                                ring_age, ring_live, ring_mod as _mod_cap,
-                               ring_slots)
+                               ring_slots, slot_set)
 from repro.core.regression import BIG, KnnRegState
 from repro.kernels import ops as kops
 
@@ -312,20 +312,20 @@ def _observe(state: RegStreamState, x_new, y_new, *, k):
     # an O(cap^2) copy of the matrix
     D = state.D.at[idx, :].set(d_row).at[:, idx].set(d_row)
 
-    y2 = state.y.at[idx].set(y_new)
+    y2 = slot_set(state.y, idx, y_new)
     own_d, own_y, _, own_a = _own_list(state, d_row, y2, y_new, k=k)
 
     new_state = RegStreamState(
-        X=state.X.at[idx].set(x_new),
+        X=slot_set(state.X, idx, x_new),
         y=y2,
         D=D,
-        nbr_d=nbr_d.at[idx].set(own_d),
-        nbr_y=nbr_y.at[idx].set(own_y),
+        nbr_d=slot_set(nbr_d, idx, own_d),
+        nbr_y=slot_set(nbr_y, idx, own_y),
         n=state.n + 1,
         head=state.head,
         aid=state.aid.at[idx].set(new_aid),
         wrap=state.wrap,
-        nbr_a=nbr_a.at[idx].set(own_a),
+        nbr_a=slot_set(nbr_a, idx, own_a),
     )
     return new_state, d_row
 

@@ -10,6 +10,13 @@
 * device tick stats — the in-graph per-tick counters from
   ``telemetry.device`` folded into a lazy device accumulator
   (``.ticks``); ``drain()`` publishes them.
+* the chunk program's memory — before each chunk dispatch the engine
+  hands ``note_program`` the executable it calls, and ``drain()``
+  reports two byte counts beside the tick stats: ``state_bytes``, one
+  chip's share of the engine state (leaf bytes over the shard count),
+  and ``chunk_temp_bytes``, that executable's temporaries
+  (``memory_analysis()``). A device's peak memory does not show the
+  temporaries apart from the state they sit beside.
 
 The timing wrapper never forces a device sync by default: ``wall_s`` is
 host wall time around the (async) dispatch. Loops that synchronize per
@@ -81,6 +88,9 @@ class EngineTelemetry:
             self.stats_fn = None
             self.ticks = None
         self._seen: set = set()
+        # the last chunk executable noted, and its byte counts
+        self._noted = None
+        self.memory: dict[str, int] = {}
 
     def first_call(self, op: str, signature: Any) -> bool:
         key = (op, signature)
@@ -120,9 +130,28 @@ class EngineTelemetry:
                        tenants=tenants, capacity=capacity,
                        dispatch_s=handle.late.get("dispatch_s"))
 
+    def note_program(self, exe, state, *, shards: int = 1) -> None:
+        """Note the chunk executable about to run on ``state``: its
+        byte counts become the ones ``drain()`` reports (read once per
+        executable)."""
+        if exe is self._noted:
+            return
+        import jax
+
+        mem = {"state_bytes": sum(a.size * a.dtype.itemsize
+                                  for a in jax.tree_util.tree_leaves(state))
+               // shards}
+        analysis = exe.memory_analysis()
+        if analysis is not None:
+            mem["chunk_temp_bytes"] = int(analysis.temp_size_in_bytes)
+        self._noted, self.memory = exe, mem
+
     def drain(self) -> dict[str, int]:
-        """Publish accumulated device tick stats (one host sync)."""
-        return self.ticks.drain() if self.ticks is not None else {}
+        """Publish accumulated device tick stats (one host sync), with
+        the byte counts of the last chunk program dispatched."""
+        if self.ticks is None:
+            return {}
+        return {**self.ticks.drain(), **self.memory}
 
 
 __all__ = ["EngineTelemetry", "_TimedHandle"]

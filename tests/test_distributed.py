@@ -185,6 +185,108 @@ _PAD_SCRIPT = _PRELUDE + textwrap.dedent("""
 """)
 
 
+_MANY_SCRIPT = _PRELUDE + textwrap.dedent("""
+    # the many-small-tenants shape: 16 tenants x window 256 on 4 shards,
+    # past one whole window, so every ring has wrapped
+    from repro.core.measures import knn as knn_m
+    from repro.serving import session as sm
+    from repro.serving.engine import ServingEngine
+    from repro.telemetry import MetricsRegistry
+    S, CAP, K, D, T = 16, 256, 3, 5, 300
+    rng = np.random.default_rng(5)
+    xs = jnp.asarray(rng.normal(size=(T, S, D)), jnp.float32)
+    ys = jnp.asarray(rng.integers(0, 2, size=(T, S)), jnp.int32)
+    taus = jnp.asarray(rng.uniform(size=(T, S)), jnp.float32)
+    runs = {}
+    for shards, instrument in ((1, True), (4, True), (4, False)):
+        kw = dict(instrument=True, metrics=MetricsRegistry()) \
+            if instrument else {}
+        eng = ServingEngine(n_sessions=S, capacity=CAP, dim=D, n_labels=2,
+                            k=K, window=CAP, shards=shards, **kw)
+        st = eng.init_state()
+        if shards > 1:
+            assert all(len(a.sharding.device_set) == shards
+                       for a in jax.tree_util.tree_leaves(st))
+        # a profile names the tick program alike on any shard count
+        assert eng.lower_tick(2).as_text().startswith("module @jit_chunk")
+        ps = []
+        for t0 in range(0, T, 20):
+            st, p = eng.observe_many(st, xs[t0:t0 + 20], ys[t0:t0 + 20],
+                                     taus[t0:t0 + 20])
+            ps.append(np.asarray(p))
+        runs[shards, instrument] = (st, np.concatenate(ps))
+        if instrument:
+            got = eng.telemetry.drain()
+            leaf = sum(a.size * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(st))
+            assert got["state_bytes"] == leaf // shards, (got, leaf)
+            assert got["chunk_temp_bytes"] > 0
+    ref_st, ref_p = runs[1, True]
+    assert int(jnp.min(ref_st.head)) > 0  # every ring wrapped
+    for key, (st, p) in runs.items():
+        assert leaves_equal(st, ref_st), f"state mismatch @{key}"
+        assert np.array_equal(p, ref_p, equal_nan=True), f"pvals @{key}"
+    # the fit oracle: a fresh engine fed only the surviving window holds
+    # the same k-NN lists and distances, bit for bit, once both rings are
+    # laid out in arrival order; and the lists equal a batch refit
+    fresh = ServingEngine(n_sessions=S, capacity=CAP, dim=D, n_labels=2,
+                          k=K, window=CAP)
+    fs, _ = fresh.observe_many(fresh.init_state(), xs[T - CAP:],
+                               ys[T - CAP:], taus[T - CAP:])
+    a, b = jax.vmap(sm.to_linear)(ref_st), jax.vmap(sm.to_linear)(fs)
+    assert np.array_equal(np.asarray(a.knn.best), np.asarray(b.knn.best))
+    assert np.array_equal(np.asarray(a.D), np.asarray(b.D))
+    for s in (0, 7, 15):
+        fit = knn_m.fit(xs[T - CAP:, s], ys[T - CAP:, s], k=K)
+        np.testing.assert_allclose(np.asarray(a.knn.best[s]),
+                                   np.asarray(fit.best_same), atol=1e-5)
+    print("MANY_SHARDED_OK")
+""")
+
+_PLACE_SCRIPT = _PRELUDE + textwrap.dedent("""
+    # the ``place`` span: a sharded engine's dispatch lays its arguments
+    # out on the tenant mesh inside it; one shard has no such span
+    import tempfile
+    from pathlib import Path
+    from jax.profiler import ProfileData
+    from repro.serving.engine import ServingEngine
+
+    def spans(eng):
+        st = eng.init_state()
+        st, _ = eng.observe_many(st, xs, ys_cls, taus)  # compile first
+        d = tempfile.mkdtemp()
+        jax.profiler.start_trace(d)
+        st, p = eng.observe_many(st, xs, ys_cls, taus)
+        jax.block_until_ready(p)
+        jax.profiler.stop_trace()
+        path = sorted(Path(d).rglob("*.xplane.pb"))[-1]
+        return sorted({e.name for pl in ProfileData.from_file(
+            str(path)).planes for ln in pl.lines for e in ln.events
+            if e.name.startswith("repro.")})
+
+    for shards in (1, 4):
+        eng = ServingEngine(n_sessions=S, capacity=CAP, dim=D, n_labels=3,
+                            k=K, window=W, shards=shards)
+        got = spans(eng)
+        want = ["repro.launch", "repro.observe_many", "repro.prepare"]
+        if shards > 1:
+            want = sorted(want + ["repro.place"])
+        assert got == want, (shards, got)
+    print("PLACE_SPAN_OK")
+""")
+
+
+def test_many_small_tenants_sharded_bit_identical_and_exact():
+    """16 tenants x window 256 over 4 shards after a wrapped window:
+    bit-identical to one shard, instrumented or not, and to the fit
+    oracle; ``state_bytes`` is one shard's share."""
+    _run_child(_MANY_SCRIPT, "MANY_SHARDED_OK")
+
+
+def test_sharded_dispatch_writes_place_span():
+    _run_child(_PLACE_SCRIPT, "PLACE_SPAN_OK")
+
+
 def _run_child(script: str, sentinel: str) -> None:
     r = subprocess.run([sys.executable, "-c", script],
                        capture_output=True, text=True, timeout=600)

@@ -133,14 +133,21 @@ def test_stream_update_matches_ref(cap, p, k, n, mode):
                                       err_msg="fast " + name)
 
 
-@pytest.mark.parametrize("cap,k,n,head,wrap", [
-    (64, 5, 40, 30, 64),   # wrapped over the full capacity
-    (64, 3, 20, 15, 24),   # window-confined ring: slots >= wrap inert
-    (70, 4, 24, 23, 24),   # full confined ring, head mid-block
-    (32, 2, 0, 7, 16),     # empty ring, nonzero head
+@pytest.mark.parametrize("cap,k,n,head,wrap,block_n", [
+    (64, 5, 40, 30, 64, 32),   # wrapped over the full capacity
+    (64, 3, 20, 15, 24, 32),   # window-confined ring: slots >= wrap inert
+    (70, 4, 24, 23, 24, 32),   # full confined ring, head mid-block
+    (32, 2, 0, 7, 16, 32),     # empty ring, nonzero head
+    # the paper's widths (k 15) at caps 21, 200, 256 and 1024, in the
+    # lane blocks the chip takes (the whole window)
+    (21, 15, 21, 13, 21, None),
+    (200, 15, 200, 151, 200, None),
+    (256, 15, 256, 255, 256, None),
+    (1024, 15, 1000, 900, 1024, None),
 ])
 @pytest.mark.parametrize("mode", ["class", "reg"])
-def test_stream_update_ring_mode_matches_ref(cap, k, n, head, wrap, mode):
+def test_stream_update_ring_mode_matches_ref(cap, k, n, head, wrap, block_n,
+                                             mode):
     """Ring-slot liveness (head/wrap) in the fused kernel vs the oracle:
     the live window is slots (head + i) % wrap, everything else inert."""
     p = 6
@@ -158,7 +165,7 @@ def test_stream_update_ring_mode_matches_ref(cap, k, n, head, wrap, mode):
             jnp.float32(0.25)
     args = (X, y_in, nbr_d, nbr_y, x_new, y_new, jnp.int32(n))
     kw = dict(mode=mode, head=jnp.int32(head), wrap=jnp.int32(wrap))
-    got = su_pallas(*args, block_n=32, interpret=True, **kw)
+    got = su_pallas(*args, block_n=block_n, interpret=True, **kw)
     want = ref.stream_update(*args, **kw)
     fast = ref.stream_update_fast(*args, **kw)
     for g, f, w, name in zip(got, fast, want, ["d_row", "nbr_d", "nbr_y"]):
@@ -170,6 +177,45 @@ def test_stream_update_ring_mode_matches_ref(cap, k, n, head, wrap, mode):
                                    err_msg=name)
     # liveness itself: exactly n slots carry finite distances
     assert int(np.sum(np.asarray(want[0]) < 1e29)) == n
+
+
+@pytest.mark.parametrize("cap,block_n", [(21, None), (256, None),
+                                         (200, 64)])
+@pytest.mark.parametrize("mode", ["class", "reg"])
+def test_stream_update_batched_matches_ref(cap, block_n, mode):
+    """Under ``vmap`` over tenants the kernel runs once on the stacked
+    tenants (window on the lanes, tenants on the sublanes, a tenant
+    block overhanging the end): each tenant's outputs match the oracle
+    run on that tenant alone, rings wrapped at their own heads."""
+    B, p, k = 19, 30, 15
+    ks = jax.random.split(jax.random.PRNGKey(cap + B), 8)
+    X = jax.random.normal(ks[0], (B, cap, p), jnp.float32)
+    nbr_d = jnp.sort(jax.random.uniform(ks[2], (B, cap, k), jnp.float32,
+                                        0.1, 9.0), axis=-1)
+    nbr_y = jax.random.normal(ks[3], (B, cap, k), jnp.float32)
+    x_new = jax.random.normal(ks[4], (B, p), jnp.float32)
+    if mode == "class":
+        y = jax.random.randint(ks[1], (B, cap), 0, 2, jnp.int32)
+        y_new = jax.random.randint(ks[5], (B,), 0, 2, jnp.int32)
+    else:
+        y = jax.random.normal(ks[1], (B, cap), jnp.float32)
+        y_new = jax.random.normal(ks[5], (B,), jnp.float32)
+    n = jax.random.randint(ks[6], (B,), 0, cap + 1, jnp.int32)
+    head = jax.random.randint(ks[7], (B,), 0, cap, jnp.int32)
+    wrap = jnp.full((B,), cap, jnp.int32)
+    args = (X, y, nbr_d, nbr_y, x_new, y_new, n)
+    got = jax.vmap(lambda *a: su_pallas(
+        *a[:7], mode=mode, block_n=block_n, interpret=True, head=a[7],
+        wrap=a[8]))(*args, head, wrap)
+    for b in range(B):
+        want = ref.stream_update(*(a[b] for a in args), mode=mode,
+                                 head=head[b], wrap=wrap[b])
+        for g, w, name in zip(got, want, ["d_row", "nbr_d", "nbr_y"]):
+            g, w = np.asarray(g[b]), np.asarray(w)
+            big = w >= 1e29
+            np.testing.assert_array_equal(g[big], w[big], err_msg=name)
+            np.testing.assert_allclose(g[~big], w[~big], atol=1e-5,
+                                       rtol=1e-5, err_msg=name)
 
 
 @pytest.mark.parametrize("mode", ["class", "reg"])

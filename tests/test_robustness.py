@@ -265,8 +265,8 @@ def test_guard_bit_identical_on_clean_traffic(mode):
     assert rep["quarantines"] == 0 and rep["quarantined_lanes"] == []
     # the guarded path dispatches the same compiled engine step: one
     # cache entry each, zero new retraces
-    assert guarded.engine._step_many._cache_size() == 1
-    assert plain._step_many._cache_size() == 1
+    assert len(guarded.engine._chunks) == 1
+    assert len(plain._chunks) == 1
 
 
 @pytest.mark.parametrize("mode", ["classification", "regression"])
@@ -586,5 +586,5 @@ def test_chaos_surviving_tenants_bit_identical(tmp_path, mode):
     assert _metric_sum(metrics, "faults_injected_total") >= 2
     assert store.latest_step() == 3  # the retried snapshot committed
     # the guard never changed the engine's dispatch signature
-    assert eng._step_many._cache_size() == 1
-    assert oracle._step_many._cache_size() == 1
+    assert len(eng._chunks) == 1
+    assert len(oracle._chunks) == 1

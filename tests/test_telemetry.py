@@ -303,7 +303,7 @@ def test_device_tick_stats_match_offline_simulation():
             else:
                 total[k] += ref[k]
     got = eng.telemetry.drain()
-    assert got == total
+    assert {k: got[k] for k in STAT_KEYS} == total
     # published under engine_* with the run totals
     assert reg.counter("engine_ticks_total",
                        engine="classification").value == total["ticks"]
@@ -311,7 +311,8 @@ def test_device_tick_stats_match_offline_simulation():
                      engine="classification").value == \
         total["occupancy_max"]
     # drained: a second drain is empty and totals persist
-    assert eng.telemetry.drain() == {k: 0 for k in STAT_KEYS}
+    again = eng.telemetry.drain()
+    assert {k: again[k] for k in STAT_KEYS} == {k: 0 for k in STAT_KEYS}
     assert eng.telemetry.ticks.totals["evictions"] == total["evictions"]
 
 
@@ -437,6 +438,52 @@ def test_engines_write_dispatch_spans(instrument, tmp_path):
     # each engine numbers its own dispatches, two each before the profile
     seqs = [sp[3] for sp in sorted(outer, key=lambda sp: sp[1])]
     assert seqs == [3, 3, 4, 4]
+
+
+def _chunk_args(eng, state, xs, ys, taus):
+    return (state, xs, ys, taus, eng._windows(state),
+            jnp.ones(xs.shape[:2], bool))
+
+
+@pytest.mark.parametrize("kind", ["class", "reg"])
+def test_drain_reports_the_dispatched_chunk_program_bytes(kind):
+    """``state_bytes`` is the state's leaf bytes (one shard); the
+    dispatch calls the executable compiled ahead of time for its shape,
+    and ``chunk_temp_bytes`` is that executable's own temporaries: no
+    later dispatch of the shape compiles again."""
+    eng = _tiny_engine(kind, instrument=True, metrics=MetricsRegistry())
+    xs, ys, taus = _class_traffic(3, 4, 3, seed=2)
+    ys = ys if kind == "class" else ys.astype(jnp.float32)
+    state = eng.init_state()
+    leaf_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(state))
+    for _ in range(2):  # the second folds the stats: its add compiles
+        state, _ = eng.observe_many(state, xs, ys, taus)
+    assert len(eng._chunks) == 1
+    exe = eng._chunks(_chunk_args(eng, state, xs, ys, taus))
+    assert eng.telemetry._noted is exe
+    mem = eng.telemetry.memory
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        state, _ = eng.observe_many(state, xs, ys, taus)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    got = eng.telemetry.drain()
+    assert got["state_bytes"] == leaf_bytes
+    assert got["chunk_temp_bytes"] == \
+        exe.memory_analysis().temp_size_in_bytes == mem["chunk_temp_bytes"]
+    # the same program the engine's jitted chunk compiles to
+    again = eng._step_many.lower(
+        *_chunk_args(eng, state, xs, ys, taus)).compile()
+    assert got["chunk_temp_bytes"] == \
+        again.memory_analysis().temp_size_in_bytes
 
 
 def test_engine_telemetry_without_accessors_is_timing_only():

@@ -112,27 +112,32 @@ def test_kde_score_compiles(one_chip):
                    s((M,), jnp.int32), s((CAP,), jnp.int32), h=1.0)
 
 
-def _compile_chunk(one_chip, family, cap):
+def _compile_chunk(one_chip, family, cap, tenants=TENANTS, chunk=CHUNK):
     """One served ``observe_many`` chunk of ``family``'s engine at
-    TENANTS x window ``cap``, compiled for one v5e chip; with its state's
-    shapes."""
+    ``tenants`` x window ``cap``, compiled for one v5e chip; with its
+    state's shapes."""
     if family == "classification":
         from repro.serving import ServingEngine as Engine
         extra, ydt = dict(n_labels=2), jnp.int32
     else:
         from repro.regression import RegressionServingEngine as Engine
         extra, ydt = {}, jnp.float32
-    eng = Engine(n_sessions=TENANTS, capacity=cap, dim=DIM, k=K,
+    eng = Engine(n_sessions=tenants, capacity=cap, dim=DIM, k=K,
                  window=cap, instrument=True, **extra)
     state = jax.tree_util.tree_map(
         lambda a: _spec(one_chip, a.shape, a.dtype),
         jax.eval_shape(eng.init_state))
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)
     compiled = eng._step_many.lower(
-        state, s((CHUNK, TENANTS, DIM)), s((CHUNK, TENANTS), ydt),
-        s((CHUNK, TENANTS)), s((TENANTS,), jnp.int32),
-        s((CHUNK, TENANTS), jnp.bool_)).compile()
+        state, s((chunk, tenants, DIM)), s((chunk, tenants), ydt),
+        s((chunk, tenants)), s((tenants,), jnp.int32),
+        s((chunk, tenants), jnp.bool_)).compile()
     return compiled, state
+
+
+def _state_bytes(state) -> int:
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(state))
 
 
 @pytest.mark.parametrize("family", ["classification", "regression"])
@@ -142,8 +147,7 @@ def test_engine_observe_chunk_compiles(one_chip, tpu_route, family):
     compiled, state = _compile_chunk(one_chip, family, CAP)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    state_bytes = sum(a.size * a.dtype.itemsize
-                      for a in jax.tree_util.tree_leaves(state))
+    state_bytes = _state_bytes(state)
     assert mem.alias_size_in_bytes >= state_bytes  # donation holds
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 2 ** 30)  # fits one v5e chip's HBM
@@ -180,3 +184,77 @@ def test_engine_chunk_keeps_distance_block_row_major(one_chip, tpu_route,
     assert len(relaid["entry"]) == (0 if cap % 128 == 0 else 2), relaid
     assert not re.search(rf"{block}\{{1,2,0", text)
     assert "dist_insert" in text
+
+
+def _lane_padded(text: str, tenants: int, cap: int) -> list[str]:
+    """Buffers of a (tenants, cap, m) array with m of 1, 15, 30 or 128 in
+    the row-major layout, whose minor m the chip pads to 128 lanes (or
+    that are such a padding). Values inside a fusion are never stored,
+    so the fused computations' own instructions are skipped."""
+    fused = set(re.findall(r"fusion\(.*calls=(%[\w.-]+)", text))
+    pat = re.compile(rf"%\S+ = [fs]32\[{tenants},{cap},(1|15|30|128)\]"
+                     r"\{2,1,0")
+    out, skip = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation's header
+            skip = line.split(" ")[0] in fused
+        elif not skip:
+            out += [m.group(0) for m in pat.finditer(line)]
+    return out
+
+
+def test_many_tenant_chunk_fits_one_chip(one_chip, tpu_route):
+    """The tick of 16,384 tenants x window 256 (one chip's share of the
+    many-tenant deployment), one 4-tick chunk: it compiles for one v5e
+    chip with temporaries no larger than the state it carries, and no
+    per-slot (tenants, window, m) array is padded to 128 lanes: the
+    kernel reads features and lists window-minor, in the state's own
+    layout, and the per-slot vectors as (tenants, window) rows."""
+    tenants, cap = 16384, 256
+    compiled, state = _compile_chunk(one_chip, "classification", cap,
+                                     tenants=tenants, chunk=4)
+    text = compiled.as_text()
+    assert "stream_update" in text and "dist_insert" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= _state_bytes(state)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    assert not _lane_padded(text, tenants, cap)
+
+
+@pytest.mark.parametrize("family,cap,most", [
+    ("classification", 1024, 1.08e9),  # the class cell's chunk before
+    ("regression", 512, 0.746e9),      # the reg cell's chunk before
+])
+def test_engine_chunk_temporaries_bounded(one_chip, tpu_route, family, cap,
+                                          most):
+    """At the benchmark cells' sizes the chunk's temporaries stay at or
+    under what they were with the kernel reading lane-padded copies of
+    X and the lists, and none of those copies is left."""
+    compiled, _ = _compile_chunk(one_chip, family, cap)
+    assert compiled.memory_analysis().temp_size_in_bytes <= most
+    assert not _lane_padded(compiled.as_text(), TENANTS, cap)
+
+
+@pytest.mark.parametrize("family,cap", [("classification", 1024),
+                                        ("regression", 512)])
+def test_stream_update_moves_its_bytes_from_hbm(one_chip, tpu_route, family,
+                                                cap):
+    """The kernel's operands and k-best lists live in HBM (no ``S(1)``,
+    the chip's VMEM) at the cells' sizes: staged in VMEM by XLA ahead of
+    the call, a small window's features and lists would be read at VMEM
+    speed, and the kernel's time would leave out the HBM traffic its
+    roofline share counts. The emitted distance row stays in VMEM for
+    the tick's gathers."""
+    compiled, _ = _compile_chunk(one_chip, family, cap)
+    text = compiled.as_text()
+    calls = re.findall(r"%stream_update\.\d+ = (\(.*?\)) custom-call\(([^)]*)\)",
+                       text)
+    assert calls
+    for results, operands in calls:
+        row, *lists = re.findall(r"f32\[[^\]]*\]\{[^}]*\}", results)
+        assert row.startswith(f"f32[{TENANTS},{cap}]") and "S(1)" in row
+        assert lists and not any("S(1)" in a for a in lists), results
+        for name in re.findall(r"%[\w.-]+", operands):
+            define = re.search(rf"{re.escape(name)} = (\S+)", text)
+            assert "S(1)" not in define.group(1), define.group(0)
