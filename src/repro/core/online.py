@@ -45,10 +45,10 @@ BIG = 1e30
 # which the tie rules rest on, is tracked two ways: the *age* of a slot
 # is derived from ``head`` (0 = oldest), and an explicit per-slot
 # arrival-id vector ``aid`` (a monotone counter stamped at insert)
-# provides the total order the labeled backfill breaks distance ties
-# with. ``head == 0`` with no wrap-around is exactly the historic
-# linear layout, and every function below degenerates to the old bits
-# there.
+# provides the total order the regression tick breaks distance ties
+# with (the labeled backfill's pick and the new point's own list).
+# ``head == 0`` with no wrap-around is exactly the historic linear
+# layout, and every function below degenerates to the old bits there.
 
 
 def ring_age(cap: int, head, wrap=None):
@@ -172,7 +172,7 @@ def drop_backfill_core(L, es, cand, Ds, *, k):
 
 
 def drop_backfill(L, es, cand, Ds, aff, *, k, Ly=None, La=None, ys=None,
-                  aid=None, age=None, slots=None, aid0=None):
+                  aid=None, aid0=None):
     """The one shared decremental list repair of both serving engines.
 
     For each row flagged in ``aff``: drop the first slot of the ascending
@@ -182,8 +182,8 @@ def drop_backfill(L, es, cand, Ds, aff, *, k, Ly=None, La=None, ys=None,
     bitwise untouched. Classification (``Ly is None``) repairs distances
     only and returns ``newL``.
 
-    The labeled form (regression: pass ``Ly``/``La``/``ys``/``aid`` and
-    the ring geometry ``age``/``slots``) also repairs the parallel
+    The labeled form (regression: pass ``Ly``/``La``/``ys``/``aid``/
+    ``aid0``) also repairs the parallel
     neighbour-*label* lists ``Ly`` and the neighbour-*arrival-id* lists
     ``La`` and returns ``(newL, newLy, newLa)``. The backfill label
     must follow fit's ties-toward-*earliest-arrival* order: among the
@@ -199,13 +199,14 @@ def drop_backfill(L, es, cand, Ds, aff, *, k, Ly=None, La=None, ys=None,
     wraparound int32 *difference* from ``aid0`` (the evicted — globally
     earliest — live id): live ids span at most one window of inserts,
     far below 2^31, so the differences stay exact even after the raw
-    counters overflow on a long-lived stream. The pick itself needs no
-    sort and no (slow) index-reduction: arrival *rank* is a pure
-    function of the slot (``age``), so one plain masked min over the
-    broadcast ranks finds the earliest valid rank, and ``slots`` (the
-    rank -> slot permutation, ``ring_slots``) converts it back to a
-    column index with a single gather. For a linear-layout caller
-    ``age`` and ``slots`` are both ``jnp.arange(cap)``.
+    counters overflow on a long-lived stream. The pick needs no sort and
+    no gather: ``cand`` holds live slots only, over which the ids grow
+    strictly with arrival, so the earliest valid column is the one with
+    the smallest id difference. One variadic min over the row finds that
+    difference and carries the column's label beside it (a selection,
+    so labels keep their bits, ``-0.0`` included); the id is ``aid0``
+    plus the difference. The layout never enters: ring and linear
+    callers pass the same arguments.
     """
     newL, pos0, cols, b, tprime, mprime = drop_backfill_core(
         L, es, cand, Ds, k=k)
@@ -220,7 +221,6 @@ def drop_backfill(L, es, cand, Ds, aff, *, k, Ly=None, La=None, ys=None,
     # exactly 0, below every surviving id. When b == gtmin the list
     # holds no occurrence of b (gtmin > t' strictly) and the pick is
     # simply the earliest.
-    cap = L.shape[0]
     aid0 = jnp.asarray(aid0, jnp.int32)
     rel_La = La.astype(jnp.int32) - aid0  # int32 wrap-subtract
     thr = jnp.where(
@@ -228,14 +228,17 @@ def drop_backfill(L, es, cand, Ds, aff, *, k, Ly=None, La=None, ys=None,
         jnp.max(jnp.where(L == tprime[:, None], rel_La, -1), axis=1), -1)
     rel_aid = (aid.astype(jnp.int32) - aid0)[None, :]
     valid = cand & (Ds == b[:, None]) & (rel_aid > thr[:, None])
-    # min over arrival *rank* (a pure function of the slot), then one
-    # gather through the rank -> slot permutation — no sort and no slow
-    # index-reduction anywhere in the pick
-    amin = jnp.min(jnp.where(valid, age[None, :].astype(jnp.int32), cap),
-                   axis=1)
-    sel = slots[jnp.minimum(amin, cap - 1)]
-    yb = ys[sel]  # rows where b >= BIG pick garbage, fixed up below
-    ab = aid[sel].astype(jnp.int32)
+    # the earliest valid column and its label in one pass: the smallest
+    # id difference (distinct over live slots) carries its label along
+    none = jnp.int32(jnp.iinfo(jnp.int32).max)
+    rel_min, yb = jax.lax.reduce(
+        (jnp.where(valid, rel_aid, none),
+         jnp.where(valid, ys[None, :], jnp.zeros((), ys.dtype))),
+        (none, jnp.zeros((), ys.dtype)),
+        lambda acc, x: (jnp.minimum(acc[0], x[0]),
+                        jnp.where(x[0] < acc[0], x[1], acc[1])),
+        (1,))
+    ab = aid0 + rel_min  # rows where b >= BIG pick garbage, fixed up below
 
     Lyup = jnp.concatenate([Ly[:, 1:], Ly[:, :1]], axis=1)
     newLy = jnp.where(cols[None, :] < pos0[:, None], Ly,

@@ -41,8 +41,7 @@ from repro.core.regression import BIG, _interval_ge, hull_sweep
 from repro.kernels import ops as kops
 from repro.regression import stream
 from repro.regression.stream import RegStreamState, _mod_cap, _next_aid
-from repro.core.online import (cshift, drop_backfill, ring_age, ring_live,
-                               ring_slots, slot_set)
+from repro.core.online import cshift, drop_backfill, ring_live, slot_set
 
 init = stream.init
 
@@ -106,7 +105,7 @@ def _observe(state: RegStreamState, x_new, y_new, tau, *, k):
     cap = state.capacity
     new_state, d_row = stream.observe(state, x_new, y_new, k=k)
     live = ring_live(cap, state.head, state.n, state.wrap)
-    _, _, y_sel, _ = stream._own_list(state, d_row, state.y, y_new, k=k)
+    _, _, y_sel, _ = stream._own_list(state, d_row, y_new, k=k)
     p = _price(d_row, y_sel, y_new, tau, k=k, live=live,
                nbr_d=state.nbr_d, nbr_y=state.nbr_y, y=state.y,
                n=state.n)
@@ -166,8 +165,7 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
             nbr_d1, nbr_y1, nbr_a1 = drop_backfill(
                 state.nbr_d[:w], dcol, live1[None, :], Dw, affected, k=k,
                 Ly=state.nbr_y[:w], La=state.nbr_a[:w], ys=yw, aid=aidw,
-                age=ring_age(w, head1, wrap),
-                slots=ring_slots(w, head1, wrap), aid0=aidw[head])
+                aid0=aidw[head])
         else:
             head1, n1 = head, n
             nbr_d1, nbr_y1 = state.nbr_d[:w], state.nbr_y[:w]
@@ -181,11 +179,10 @@ def _sliding_step(state: RegStreamState, x_new, y_new, tau, window, active,
         d_row, nbr_d_m, nbr_y_m = kops.stream_update(
             Xw, yw, nbr_d1, nbr_y1, x_new, y_new, n1, mode="reg",
             head=head1, wrap=wrap)
-        y2w = slot_set(yw, idx, y_new, act)
         sub = RegStreamState(Xw, yw, Dw, nbr_d1, nbr_y1, n1, head1, aidw,
                              wrap, nbr_a1)
-        own_d, own_y, y_sel, own_a = stream._own_list(sub, d_row, y2w,
-                                                      y_new, k=k)
+        own_d, own_y, y_sel, own_a = stream._own_list(sub, d_row, y_new,
+                                                      k=k)
         new_aid = _next_aid(aidw, head1, n1, wrap)
         enters = live1 & (d_row < nbr_d1[:, -1])
         nbr_a_m = stream._merge_aid(nbr_d1, nbr_a1,
@@ -283,8 +280,7 @@ def _sliding_step_compact(state: RegStreamState, x_new, y_new, tau, window,
         live1 = jnp.arange(cap) < n1
         nbr_d1, nbr_y1, nbr_a1 = drop_backfill(
             L1, es1, live1[None, :], D1, aff1, k=k, Ly=Ly1, La=La1,
-            ys=y1, aid=aid1, age=jnp.arange(cap, dtype=jnp.int32),
-            slots=jnp.arange(cap, dtype=jnp.int32), aid0=aid[0])
+            ys=y1, aid=aid1, aid0=aid[0])
     else:
         X1, y1, D1 = state.X, state.y, state.D
         nbr_d1, nbr_y1, n1, aid1 = (state.nbr_d, state.nbr_y, state.n,

@@ -34,11 +34,14 @@ the scores:
   expression ``fit`` uses, which is bitwise row-decomposable and padding-
   invariant on the supported backends (checked by the property tests).
 
-Where a computation is arrival-order sensitive (the new point's own
-top-k list, whose equal-distance neighbours must be taken oldest-first),
-the (cap,) vectors are gathered through ``ring_slots`` into arrival
-order first — an O(cap) gather, after which the historic linear-layout
-expressions run unchanged and therefore produce the same bits.
+Where a computation on the tick is arrival-order sensitive (the new
+point's own top-k list, whose equal-distance neighbours must be taken
+oldest-first, and the backfill's pick), it stays in slot space: the
+arrival ids order the live window by arrival, so a sort keyed on
+(distance, id) or a min over ids breaks the ties, and the (cap,) vectors
+are never permuted into arrival order. The read paths still gather
+through ``ring_slots`` into arrival order, after which the historic
+linear-layout expressions run unchanged.
 
 All arrays are capacity-padded and fixed-shape, so every update is one
 jit-stable dispatch and vmaps across tenants (``repro.regression.engine``).
@@ -52,7 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.online import (drop_backfill, next_aid as _next_aid,
-                               ring_age, ring_live, ring_mod as _mod_cap,
+                               ring_live, ring_mod as _mod_cap,
                                ring_slots, slot_set)
 from repro.core.regression import BIG, KnnRegState
 from repro.kernels import ops as kops
@@ -248,37 +251,51 @@ def state_view(state: RegStreamState, *, k) -> KnnRegState:
     return KnnRegState(X, y, a_prime, kth_d, kth_y)
 
 
-def _own_list(state: RegStreamState, d_row, y2, y_new, *, k):
-    """The new point's own (distances, labels) k-NN list, plus the
-    arrival-order top-k index set that produced it.
+def _total_order_key(v):
+    """Signed integers that order like the floats ``v`` under IEEE total
+    order (-0.0 before +0.0), the order ``top_k`` compares in; the map
+    is its own inverse on the bits."""
+    ity = jnp.dtype(f"int{8 * v.dtype.itemsize}")
+    bits = jax.lax.bitcast_convert_type(v, ity)
+    return bits ^ ((bits >> (8 * v.dtype.itemsize - 1))
+                   & jnp.iinfo(ity).max)
 
-    ``fit`` breaks equal-distance ties toward the earliest arrival, so
-    the top_k must run over the distance row in *arrival* order — under
-    the ring layout that is a gather through ``ring_slots``, with labels
-    masked to the linear path's inert 0 beyond ``n`` (garbage labels of
-    stale slots must not leak into the degenerate n < k sums).
+
+def _own_list(state: RegStreamState, d_row, y_new, *, k):
+    """The new point's own (distances, labels) k-NN list.
+
+    ``fit`` takes the k nearest in ascending distance with ties toward
+    the earliest arrival, as ``top_k`` over the row in arrival order
+    does. Here the row stays in slot order: over the live window the
+    arrival ids grow strictly with arrival (as wraparound differences
+    from the oldest's), so one two-key sort on (distance in ``top_k``'s
+    total order, id difference) puts the same elements in the same
+    order, carrying each slot's label. Slots off the live window sort
+    last as BIG with label 0, the linear path's inert fills (garbage
+    labels of stale slots must not leak into the degenerate n < k sums).
     Returns ``(own_d, own_y, y_sel, own_a)`` where ``y_sel`` are the
     selected *pre-learn* labels (the pricing path's ``a`` statistic) and
     ``own_a`` the selected neighbours' arrival ids (0 at BIG slots).
+    ``own_y`` is ``y_sel`` with BIG slots relabelled: the new point's
+    own slot is rank n, off the window, so its label never enters.
     """
     cap = state.capacity
-    slots = ring_slots(cap, state.head, state.wrap)
-    pos_live = jnp.arange(cap) < state.n
-    # the explicit mask scrubs rank >= wrap alias positions; at ranks in
-    # [n, wrap) the gathered row is already BIG, so this is bit-neutral
-    d_arr = jnp.where(pos_live, d_row[slots], BIG)
-    y_arr = jnp.where(pos_live, y2[slots], 0.0)
-    y_pre = jnp.where(pos_live, state.y[slots], 0.0)
-    a_arr = jnp.where(pos_live, state.aid[slots], 0)
-    own_neg, own_idx = jax.lax.top_k(-d_arr, k)
-    own_d = -own_neg
-    own_y = y_arr[own_idx]
+    live = ring_live(cap, state.head, state.n, state.wrap)
+    aid0 = state.aid[state.head]
+    key, rel, y_sel = jax.lax.sort(
+        (_total_order_key(jnp.where(live, d_row, BIG)),
+         jnp.where(live, state.aid - aid0, jnp.iinfo(jnp.int32).max),
+         jnp.where(live, state.y, 0.0)),
+        num_keys=2, is_stable=False)  # the keys of live slots are distinct
+    own_d = jax.lax.bitcast_convert_type(_total_order_key(key[:k]),
+                                         d_row.dtype)
+    y_sel = y_sel[:k]
     # missing-neighbour slots carry the row's own label (fit convention:
     # at n == k the one BIG entry is the masked self-diagonal) and the
     # neutral arrival id 0
-    own_y = jnp.where(own_d >= BIG, y_new, own_y)
-    own_a = jnp.where(own_d >= BIG, 0, a_arr[own_idx]).astype(jnp.int32)
-    return own_d, own_y, y_pre[own_idx], own_a
+    own_y = jnp.where(own_d >= BIG, y_new, y_sel)
+    own_a = jnp.where(own_d >= BIG, 0, aid0 + rel[:k])
+    return own_d, own_y, y_sel, own_a
 
 
 def _observe(state: RegStreamState, x_new, y_new, *, k):
@@ -312,12 +329,11 @@ def _observe(state: RegStreamState, x_new, y_new, *, k):
     # an O(cap^2) copy of the matrix
     D = state.D.at[idx, :].set(d_row).at[:, idx].set(d_row)
 
-    y2 = slot_set(state.y, idx, y_new)
-    own_d, own_y, _, own_a = _own_list(state, d_row, y2, y_new, k=k)
+    own_d, own_y, _, own_a = _own_list(state, d_row, y_new, k=k)
 
     new_state = RegStreamState(
         X=slot_set(state.X, idx, x_new),
-        y=y2,
+        y=slot_set(state.y, idx, y_new),
         D=D,
         nbr_d=slot_set(nbr_d, idx, own_d),
         nbr_y=slot_set(nbr_y, idx, own_y),
@@ -442,8 +458,7 @@ def _evict_oldest(state: RegStreamState, *, k) -> RegStreamState:
     nbr_d2, nbr_y2, nbr_a2 = drop_backfill(
         state.nbr_d, dcol, cand, state.D, affected, k=k,
         Ly=state.nbr_y, La=state.nbr_a, ys=state.y, aid=state.aid,
-        age=ring_age(cap, head2, state.wrap),
-        slots=ring_slots(cap, head2, state.wrap), aid0=state.aid[head])
+        aid0=state.aid[head])
     return RegStreamState(
         X=state.X, y=state.y, D=state.D, nbr_d=nbr_d2, nbr_y=nbr_y2,
         n=n2, head=head2, aid=state.aid, wrap=state.wrap, nbr_a=nbr_a2)

@@ -30,7 +30,9 @@ try:
 except ImportError:  # pragma: no cover - exercised in bare containers
     HAS_HYPOTHESIS = False
 
+from repro.core import online
 from repro.core import regression as reg
+from repro.core.online import BIG
 from repro.data.synthetic import make_classification, make_regression
 from repro.regression import RegressionServingEngine
 from repro.regression import session as rsess
@@ -445,6 +447,149 @@ def test_arrival_id_wraparound_is_harmless():
     view = rstream.state_view(b, k=k)
     np.testing.assert_array_equal(np.asarray(view.kth_label)[:T],
                                   np.asarray(fit.kth_label))
+
+
+# ---------------------------------------------------------------------------
+# the tick's arrival-order picks in slot space == the arrival-order gathers
+# ---------------------------------------------------------------------------
+
+
+def _own_list_gather(state, d_row, y2, y_new, *, k):
+    """The own list as ``top_k`` over the row gathered into arrival order
+    through ``ring_slots`` (the reference form); ``y2`` is the post-learn
+    label vector the gathering callers passed."""
+    cap = state.capacity
+    slots = rstream.ring_slots(cap, state.head, state.wrap)
+    pos_live = jnp.arange(cap) < state.n
+    d_arr = jnp.where(pos_live, d_row[slots], BIG)
+    y_arr = jnp.where(pos_live, y2[slots], 0.0)
+    y_pre = jnp.where(pos_live, state.y[slots], 0.0)
+    a_arr = jnp.where(pos_live, state.aid[slots], 0)
+    own_neg, own_idx = jax.lax.top_k(-d_arr, k)
+    own_d = -own_neg
+    own_y = jnp.where(own_d >= BIG, y_new, y_arr[own_idx])
+    own_a = jnp.where(own_d >= BIG, 0, a_arr[own_idx]).astype(jnp.int32)
+    return own_d, own_y, y_pre[own_idx], own_a
+
+
+def _drop_backfill_gather(L, es, cand, Ds, aff, *, k, Ly, La, ys, aid,
+                          aid0, age, slots):
+    """The labeled list repair with the pick as a min over arrival rank
+    ``age`` and gathers through the rank -> slot permutation ``slots``
+    (the reference form)."""
+    newL, pos0, cols, b, tprime, _ = online.drop_backfill_core(
+        L, es, cand, Ds, k=k)
+    cap = L.shape[0]
+    aid0 = jnp.asarray(aid0, jnp.int32)
+    thr = jnp.where(
+        b == tprime,
+        jnp.max(jnp.where(L == tprime[:, None], La - aid0, -1), axis=1), -1)
+    valid = cand & (Ds == b[:, None]) & ((aid - aid0)[None, :] > thr[:, None])
+    amin = jnp.min(jnp.where(valid, age[None, :], cap), axis=1)
+    sel = slots[jnp.minimum(amin, cap - 1)]
+    yb, ab = ys[sel], aid[sel]
+    tail = lambda M, v: jnp.where(
+        cols[None, :] < pos0[:, None], M,
+        jnp.where(cols[None, :] < k - 1,
+                  jnp.concatenate([M[:, 1:], M[:, :1]], axis=1), v[:, None]))
+    newLy = jnp.where(newL >= BIG, ys[:, None], tail(Ly, yb))
+    newLa = jnp.where(newL >= BIG, 0, tail(La, ab))
+    return (jnp.where(aff[:, None], newL, L),
+            jnp.where(aff[:, None], newLy, Ly),
+            jnp.where(aff[:, None], newLa, La))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.dtype(f"u{a.dtype.itemsize}"))
+
+
+# (ticks, window, capacity, ring modulus, evictions after, k, data)
+_PICK_CASES = {
+    "head-at-seam": (32, 16, 16, 16, 0, 4, "grid"),
+    "straddles-seam": (31, 16, 16, 16, 0, 4, "grid"),
+    "past-seam": (37, 16, 16, 16, 0, 4, "grid"),
+    "confined-ring": (37, 16, 24, 16, 0, 4, "grid"),
+    "n-below-k": (3, 16, 16, 16, 0, 5, "grid"),
+    "drained-below-k": (37, 16, 16, 16, 12, 5, "grid"),
+    "n-equals-wrap": (16, 32, 16, 16, 0, 4, "grid"),
+    "ids-wrap-int32": (37, 16, 16, 16, 0, 4, "grid-idwrap"),
+    "continuous": (37, 16, 16, 16, 0, 4, "continuous"),
+}
+
+
+def _pick_state(case):
+    """A ring state from a real sliding stream; grid features force
+    distance ties, grid labels include -0.0; optionally its arrival ids
+    shifted so that they straddle INT32_MAX."""
+    T, window, cap, wrap, drain, k, data = _PICK_CASES[case]
+    rng = np.random.RandomState(T + cap + drain)
+    if data == "continuous":
+        X, y, _ = _reg_stream(T + 1, seed=4)
+    else:
+        X = jnp.asarray(rng.randint(0, 2, size=(T + 1, DIM)), jnp.float32)
+        y = jnp.asarray(rng.choice([-0.0, 0.0, 1.0, 2.0], size=T + 1),
+                        jnp.float32)
+    st = rstream.init(cap, DIM, k, wrap=wrap)
+    for t in range(T):
+        if int(st.n) >= window:
+            st = rstream.evict_oldest(st, k=k)
+        st, _ = rstream.observe(st, X[t], y[t], k=k)
+    for _ in range(drain):
+        st = rstream.evict_oldest(st, k=k)
+    if data.endswith("idwrap"):
+        live = rstream.ring_live(cap, st.head, st.n, st.wrap)
+        off = jnp.int32(2**31 - 1 - T + int(st.n) // 2)
+        st = rstream.RegStreamState(
+            st.X, st.y, st.D, st.nbr_d, st.nbr_y, st.n, st.head,
+            jnp.where(live, st.aid + off, st.aid), st.wrap,
+            jnp.where(st.nbr_d < BIG, st.nbr_a + off, st.nbr_a))
+        ids = np.asarray(st.aid)[np.asarray(live)]
+        assert ids.min() < 0 < ids.max()  # the live ids wrap
+    return st, X[T], y[T], k
+
+
+@pytest.mark.parametrize("case", list(_PICK_CASES))
+def test_own_list_sorts_like_arrival_order_gather(case):
+    """The own list from one two-key sort in slot order is bit-identical
+    to ``top_k`` over the row gathered into arrival order: distances,
+    labels (``-0.0`` kept), selected pre-learn labels, arrival ids."""
+    st, x_new, y_new, k = _pick_state(case)
+    _, d_row = rstream.observe(st, x_new, y_new, k=k)
+    full = int(st.n) == int(st.wrap)
+    # a gated caller at a full window learns nothing: its post-learn
+    # labels are the pre-learn ones
+    idx = (int(st.head) + int(st.n)) % int(st.wrap)
+    y2 = st.y if full else st.y.at[idx].set(y_new)
+    new = jax.jit(functools.partial(rstream._own_list, k=k))(
+        st, d_row, y_new)
+    ref = jax.jit(functools.partial(_own_list_gather, k=k))(
+        st, d_row, y2, y_new)
+    for name, a, b in zip(("own_d", "own_y", "y_sel", "own_a"), new, ref):
+        np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=name)
+
+
+@pytest.mark.parametrize("case", list(_PICK_CASES))
+def test_backfill_pick_matches_arrival_rank_gather(case):
+    """The labeled backfill's pick as a min over arrival ids that carries
+    the label is bit-identical to the min over arrival rank followed by
+    gathers through ``ring_slots``: every repaired list, on every row."""
+    st, _, _, k = _pick_state(case)
+    cap, wrap, head = st.capacity, st.wrap, st.head
+    head2 = (head + 1) % wrap
+    live2 = rstream.ring_live(cap, head2, st.n - 1, wrap)
+    dcol = st.D[:, head]
+    aff = live2 & (dcol <= st.nbr_d[:, -1])
+    assert bool(aff.any())
+    args = (st.nbr_d, dcol, live2[None, :], st.D, aff)
+    kw = dict(k=k, Ly=st.nbr_y, La=st.nbr_a, ys=st.y, aid=st.aid,
+              aid0=st.aid[head])
+    new = jax.jit(functools.partial(online.drop_backfill, **kw))(*args)
+    ref = jax.jit(functools.partial(
+        _drop_backfill_gather, **kw, age=online.ring_age(cap, head2, wrap),
+        slots=online.ring_slots(cap, head2, wrap)))(*args)
+    for name, a, b in zip(("nbr_d", "nbr_y", "nbr_a"), new, ref):
+        np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=name)
 
 
 # ---------------------------------------------------------------------------

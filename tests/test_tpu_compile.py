@@ -17,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -186,6 +187,25 @@ def test_engine_chunk_keeps_distance_block_row_major(one_chip, tpu_route,
     assert "dist_insert" in text
 
 
+def test_regression_tick_gathers_no_slot_vector(one_chip, tpu_route):
+    """The regression tick never permutes a (window,) vector into arrival
+    order: the compiled chunk at 256 tenants x window 512 holds no gather
+    with slice sizes all 1 over tenants x window indices (with unit
+    slices, a gather's result has one element per index). The own list
+    and the backfill pick order by arrival id in slot space; gathers of
+    a scalar or a whole row per tenant remain."""
+    cap = 512
+    compiled, _ = _compile_chunk(one_chip, "regression", cap)
+    gathers = re.findall(r"= [a-z]+\d+\[([\d,]*)\]\S* gather\(.*?"
+                         r"slice_sizes=\{([\d,]*)\}", compiled.as_text())
+    assert gathers  # the pattern still reads the compiled text
+    per_element = [
+        (shape, sizes) for shape, sizes in gathers
+        if set(sizes.split(",")) == {"1"}
+        and np.prod([int(d) for d in shape.split(",") if d]) == TENANTS * cap]
+    assert not per_element, per_element
+
+
 def _lane_padded(text: str, tenants: int, cap: int) -> list[str]:
     """Buffers of a (tenants, cap, m) array with m of 1, 15, 30 or 128 in
     the row-major layout, whose minor m the chip pads to 128 lanes (or
@@ -244,8 +264,8 @@ def test_stream_update_moves_its_bytes_from_hbm(one_chip, tpu_route, family,
     the chip's VMEM) at the cells' sizes: staged in VMEM by XLA ahead of
     the call, a small window's features and lists would be read at VMEM
     speed, and the kernel's time would leave out the HBM traffic its
-    roofline share counts. The emitted distance row stays in VMEM for
-    the tick's gathers."""
+    roofline share counts. The emitted distance row stays in VMEM, where
+    the tick's later passes read it."""
     compiled, _ = _compile_chunk(one_chip, family, cap)
     text = compiled.as_text()
     calls = re.findall(r"%stream_update\.\d+ = (\(.*?\)) custom-call\(([^)]*)\)",
