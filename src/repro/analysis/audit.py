@@ -170,11 +170,8 @@ class Artifact:
                   window=t.window if t.mode == "sliding" else None,
                   layout=t.layout, donate=t.donate, shards=t.shards)
         kw.update(overrides)
-        if t.family == "classification":
-            from repro.serving.engine import ServingEngine
-            return ServingEngine(n_labels=2, **kw)
-        from repro.regression.engine import RegressionServingEngine
-        return RegressionServingEngine(**kw)
+        from repro.serving.families import ENGINES
+        return ENGINES[t.family](**kw)
 
     def engine(self):
         if self._engine is None:
@@ -332,35 +329,21 @@ def check_retrace(target: AuditTarget, art: Artifact) -> dict:
         for i in range(3):
             x = jnp.full((t.n_sessions, t.dim), 0.1 * (i + 1),
                          jnp.float32)
-            y = (jnp.zeros((t.n_sessions,), jnp.int32)
-                 if t.family == "classification"
-                 else jnp.zeros((t.n_sessions,), jnp.float32))
+            y = jnp.zeros((t.n_sessions,), eng.y_dtype)
             tau = jnp.full((t.n_sessions,), 0.5, jnp.float32)
             state, _ = eng.observe(state, x, y, tau)
         xs = jnp.zeros((t.chunk, t.n_sessions, t.dim), jnp.float32)
-        ys = (jnp.zeros((t.chunk, t.n_sessions), jnp.int32)
-              if t.family == "classification"
-              else jnp.zeros((t.chunk, t.n_sessions), jnp.float32))
+        ys = jnp.zeros((t.chunk, t.n_sessions), eng.y_dtype)
         ts = jnp.full((t.chunk, t.n_sessions), 0.5, jnp.float32)
         state, _ = eng.observe_many(state, xs, ys, ts)
-        xq = jnp.zeros((2, t.dim), jnp.float32)
-        if t.family == "classification":
-            eng.predict(state, xq)
-        else:
-            eng.intervals(state, xq, epsilon=0.1)
+        eng.read(state, jnp.zeros((2, t.dim), jnp.float32))
         return state
 
-    def caches():
-        read = (eng._predict if t.family == "classification"
-                else eng._intervals)
-        return {"step": len(eng._chunks),
-                "read": read._cache_size()}
-
     state = lifecycle(eng.init_state())
-    first = caches()
+    first = eng.program_counts()
     events_first = compile_events[0]
     lifecycle(state)  # identical shapes: must add ZERO compilations
-    second = caches()
+    second = eng.program_counts()
     events_second = compile_events[0] - events_first
 
     vs = []
@@ -397,7 +380,9 @@ def engine_matrix(max_shards: int, quick: bool = False) -> list:
     """Engine targets: family x mode x layout x shards."""
     targets = []
     shard_grid = (1,) if max_shards < 8 else (1, 8)
-    for family in ("classification", "regression"):
+    from repro.serving.families import ENGINES
+
+    for family in ENGINES:
         for mode in ("sliding", "grow"):
             for layout in ("ring", "compact"):
                 for shards in shard_grid:

@@ -50,7 +50,7 @@ text export. ``--metrics-out`` dumps the same snapshot as JSON and
         --metrics-out metrics.json --trace-out trace.jsonl
 
 A profile of the run (``jax.profiler.trace``) holds the engines' own
-host spans (``repro.<op>``, see ``core.engine_utils.DispatchSpans``) and
+host spans (``repro.<op>``, see ``core.engine.DispatchSpans``) and
 the named scopes of their programs, with or without these flags.
 
 ``--replay TRACE`` turns the launcher into a load-test driver
@@ -307,34 +307,33 @@ def _drive(args, drv, state, X, y, taus):
     return state, pvals, warm, dt, rate
 
 
-def _run_sessions(args, metrics, tracer):
-    """Build the classification engine and serve ``--steps`` ticks of
-    seeded drift traffic. Returns everything a report or a check of the
-    served results needs."""
+def _run_engine(args, metrics, tracer, family, traffic, drifted, banner):
+    """Build ``family``'s engine and serve ``--steps`` ticks of the
+    (S, T) ``traffic`` (X, y, taus; ``drifted`` marks the tenants whose
+    stream drifts) through it. Returns everything a report or a check
+    of the served results needs."""
     from types import SimpleNamespace
 
-    from repro.serving import ServingEngine
+    from repro.serving.families import ENGINES
 
-    S, T, dim = args.sessions, args.steps, args.dim
+    S = args.sessions
     _check_steps(args)
     _check_shards(args.shards, S)
-    eng = ServingEngine(
-        n_sessions=S, capacity=args.capacity, dim=dim, k=args.k,
+    eng = ENGINES[family].build(
+        n_sessions=S, capacity=args.capacity, dim=args.dim, k=args.k,
         n_labels=2, window=args.window, instrument=True, metrics=metrics,
         tracer=tracer, shards=args.shards)
     state = eng.init_state()
-    metrics.gauge("serve_shards", mode="classification").set(args.shards)
-    print(f"[serve] engine: {S} sessions x cap {args.capacity} "
+    metrics.gauge("serve_shards", mode=family).set(args.shards)
+    print(f"[serve] {banner}: {S} sessions x cap {args.capacity} "
           f"(window={args.window}, k={args.k}, shards={args.shards}, "
           f"chunk={args.chunk}) on {_device_line()}")
 
-    X, y, taus, drifted = _class_drift_traffic(args, S, T, dim)
-    X, y, taus = _chaos_traffic(args, X, y, taus, mode="classification")
+    X, y, taus = _chaos_traffic(args, *traffic, mode=family)
     drv, guard = _maybe_guard(args, eng, state, metrics, tracer)
     state, pvals, warm, dt, rate = _drive(args, drv, state, X, y, taus)
-    metrics.gauge("serve_wall_s", mode="classification").set(dt)
-    metrics.gauge("serve_session_steps_per_s", mode="classification").set(
-        rate)
+    metrics.gauge("serve_wall_s", mode=family).set(dt)
+    metrics.gauge("serve_session_steps_per_s", mode=family).set(rate)
     eng.telemetry.drain()
     state = _drain_guard(guard, state)
     return SimpleNamespace(eng=eng, state=state, pvals=pvals, X=X, y=y,
@@ -342,16 +341,33 @@ def _run_sessions(args, metrics, tracer):
                            steps_per_s=rate)
 
 
+def _run_sessions(args, metrics, tracer):
+    """Serve ``--steps`` ticks of seeded drift traffic on the
+    classification engine."""
+    *traffic, drifted = _class_drift_traffic(args, args.sessions,
+                                             args.steps, args.dim)
+    return _run_engine(args, metrics, tracer, "classification", traffic,
+                       drifted, "engine")
+
+
 def _serve_sessions(args) -> int:
     """Multi-tenant online CP serving on the micro-batching engine."""
     metrics, tracer = _telemetry(args)
     run = _run_sessions(args, metrics, tracer)
-    _validity_metrics(run.pvals[:, 1:], run.drifted, args,
-                      engine="classification", metrics=metrics)
+    return _report_run(args, run, metrics, tracer, warm=1)
+
+
+def _report_run(args, run, metrics, tracer, *, warm: int) -> int:
+    """Publish a served run's validity metrics (p-values from tick
+    ``warm`` on), check its snapshot roundtrip with ``--snapshot-dir``,
+    and print the report. Returns the exit code."""
+    family = run.eng.measure.name
+    _validity_metrics(run.pvals[:, warm:], run.drifted, args,
+                      engine=family, metrics=metrics)
     rc = 0
     if args.snapshot_dir:
         rc = _snapshot_roundtrip(args, run.state, run.eng, metrics, tracer)
-    _emit_report(args, metrics, tracer, mode="classification")
+    _emit_report(args, metrics, tracer, mode=family)
     return rc
 
 
@@ -464,30 +480,14 @@ def _serve_registry(args) -> int:
 
 
 def _run_regression(args, metrics, tracer):
-    """Build the regression engine, serve ``--steps`` ticks of seeded
-    drift traffic, then read exact prediction intervals for a fresh
-    query batch, every tenant in one dispatch."""
-    from types import SimpleNamespace
-
+    """Serve ``--steps`` ticks of seeded drift traffic on the regression
+    engine, then read exact prediction intervals for a fresh query
+    batch, every tenant in one dispatch."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.regression import RegressionServingEngine
-
     S, T, dim = args.sessions, args.steps, args.dim
-    _check_steps(args)
-    _check_shards(args.shards, S)
-    eng = RegressionServingEngine(
-        n_sessions=S, capacity=args.capacity, dim=dim, k=args.k,
-        window=args.window, instrument=True, metrics=metrics,
-        tracer=tracer, shards=args.shards)
-    state = eng.init_state()
-    metrics.gauge("serve_shards", mode="regression").set(args.shards)
-    print(f"[serve] regression engine: {S} sessions x cap {args.capacity} "
-          f"(window={args.window}, k={args.k}, shards={args.shards}, "
-          f"chunk={args.chunk}) on {_device_line()}")
-
     # per-tenant linear traffic y = <w_s, x> + noise; odd tenants change
     # their regression function at T/2 (streaming drift detection)
     key = jax.random.PRNGKey(args.seed)
@@ -500,20 +500,13 @@ def _run_regression(args, metrics, tracer):
     late = jnp.arange(T)[None, :] >= T // 2
     y = jnp.where(drifted[:, None] & late, y + args.drift, y)
     taus = jax.random.uniform(kt, (S, T), dtype=jnp.float32)
-    X, y, taus = _chaos_traffic(args, X, y, taus, mode="regression")
-    drv, guard = _maybe_guard(args, eng, state, metrics, tracer)
-    state, pvals, warm, dt, rate = _drive(args, drv, state, X, y, taus)
-    metrics.gauge("serve_wall_s", mode="regression").set(dt)
-    metrics.gauge("serve_session_steps_per_s", mode="regression").set(rate)
-    eng.telemetry.drain()
-    state = _drain_guard(guard, state)
-
-    Xq = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
-                           (4, dim), jnp.float32)
-    iv = np.asarray(eng.intervals(state, Xq, epsilon=args.eps))
-    return SimpleNamespace(eng=eng, state=state, pvals=pvals, X=X, y=y,
-                           taus=taus, drifted=drifted, warmup_s=warm,
-                           steps_per_s=rate, Xq=Xq, intervals=iv)
+    run = _run_engine(args, metrics, tracer, "regression", (X, y, taus),
+                      drifted, "regression engine")
+    run.Xq = jax.random.normal(jax.random.PRNGKey(args.seed + 1),
+                               (4, dim), jnp.float32)
+    run.intervals = np.asarray(run.eng.intervals(run.state, run.Xq,
+                                                 epsilon=args.eps))
+    return run
 
 
 def _serve_regression(args) -> int:
@@ -522,19 +515,13 @@ def _serve_regression(args) -> int:
 
     metrics, tracer = _telemetry(args)
     run = _run_regression(args, metrics, tracer)
-    warm = 2 * args.k  # k-NN warmup: earliest p-values are degenerate
-    _validity_metrics(run.pvals[:, warm:], run.drifted, args,
-                      engine="regression", metrics=metrics)
     iv = run.intervals
     metrics.gauge("intervals_finite_frac", engine="regression").set(
         float(np.isfinite(iv).mean()))
     metrics.gauge("intervals_median_width", engine="regression").set(
         float(np.nanmedian(iv[:, :, 1] - iv[:, :, 0])))
-    rc = 0
-    if args.snapshot_dir:
-        rc = _snapshot_roundtrip(args, run.state, run.eng, metrics, tracer)
-    _emit_report(args, metrics, tracer, mode="regression")
-    return rc
+    # k-NN warmup: the earliest p-values are degenerate
+    return _report_run(args, run, metrics, tracer, warm=2 * args.k)
 
 
 def _serve_replay(args) -> int:

@@ -12,9 +12,9 @@ decremental updates:
   capacity-doubling growth) + the padded read paths: prediction
   ``intervals`` and p-values, routed through the fused
   ``kernels/interval_sweep`` Pallas kernel on TPU;
-* ``engine``  — ``RegressionServingEngine``: one vmapped jitted step
-  advances every tenant, one vmapped dispatch serves every tenant's
-  prediction intervals.
+* ``engine``  — ``RegressionServingEngine``, ``core.engine.Engine``
+  over the session: one jitted step advances every tenant, one dispatch
+  serves every tenant's prediction intervals.
 """
 from repro.regression.engine import RegressionServingEngine
 from repro.regression.stream import RegStreamState

@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.engine import Measure
 from repro.core.regression import BIG, _interval_ge, hull_sweep
 from repro.kernels import ops as kops
 from repro.regression import stream
@@ -347,16 +348,17 @@ observe_sliding_donated = functools.partial(
     jax.jit, static_argnames=("k",), donate_argnums=(0,))(_observe_sliding)
 
 
-def grow(state: RegStreamState, factor: int = 2) -> RegStreamState:
-    """Double (by default) capacity host-side, preserving all live state.
+def repad(state: RegStreamState, capacity: int) -> RegStreamState:
+    """The state at a larger ``capacity``, host-side, all live state
+    kept.
 
-    Shapes change, so jitted steps retrace — but only O(log n) times over
-    a session's lifetime (the capacity-doubling schedule). The ring is
-    normalized to linear order first (ring positions are modulus-bound,
-    so they cannot survive a capacity change). Not jittable.
+    Shapes change, so jitted steps retrace — but only O(log n) times
+    over a session's lifetime under the capacity-doubling schedule. The
+    ring is normalized to linear order first (ring positions are
+    modulus-bound, so they cannot survive a capacity change); every leaf
+    is then padded with its inert fill. Not jittable.
     """
-    cap = state.capacity
-    extra = cap * (factor - 1)
+    extra = capacity - state.capacity
     state = stream.to_linear(state)
     return RegStreamState(
         X=jnp.pad(state.X, ((0, extra), (0, 0))),
@@ -368,9 +370,15 @@ def grow(state: RegStreamState, factor: int = 2) -> RegStreamState:
         n=state.n,
         head=state.head,
         aid=jnp.pad(state.aid, (0, extra)),
-        wrap=jnp.int32(cap * factor),
+        wrap=jnp.int32(capacity),
         nbr_a=jnp.pad(state.nbr_a, ((0, extra), (0, 0))),
     )
+
+
+def grow(state: RegStreamState, factor: int = 2) -> RegStreamState:
+    """Double (by default) capacity host-side: ``repad`` to
+    ``factor`` times the capacity."""
+    return repad(state, state.capacity * factor)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -446,6 +454,24 @@ def pvalues(state: RegStreamState, X_test, t_query, *, k):
     return (cnt + 1.0) / (state.n + 1.0)
 
 
+def poison_reg(state: RegStreamState):
+    """The lanes of a stacked state whose features, labels or neighbour
+    distances hold a non-finite value (NaN for the BIG-padded lists)."""
+    bad_x = jnp.any(~jnp.isfinite(state.X), axis=(1, 2))
+    bad_y = jnp.any(~jnp.isfinite(state.y), axis=1)
+    bad_d = jnp.any(jnp.isnan(state.nbr_d), axis=(1, 2))
+    return bad_x | bad_y | bad_d
+
+
+#: the regression binding of ``core.engine.Engine``
+MEASURE = Measure(
+    name="regression", init=init, step=_sliding_step,
+    step_compact=_sliding_step_compact, repad=repad,
+    n_of=lambda s: s.n, X_of=lambda s: s.X, y_dtype=None,
+    label_ok=lambda ys, n_labels, xp=jnp: xp.isfinite(ys),
+    poison=poison_reg)
+
+
 __all__ = ["RegStreamState", "init", "observe", "observe_donated",
-           "observe_sliding", "observe_sliding_donated", "grow",
-           "intervals", "pvalues"]
+           "observe_sliding", "observe_sliding_donated", "grow", "repad",
+           "intervals", "pvalues", "poison_reg", "MEASURE"]

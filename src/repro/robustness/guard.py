@@ -86,16 +86,12 @@ class TickGuard:
         self.metrics = metrics
         self.check_every = max(int(check_every), 1)
         S = engine.n_sessions
-        self._classification = hasattr(engine, "n_labels")
-        n_labels = getattr(engine, "n_labels", 0)
-        classification = self._classification
+        label_ok = engine.measure.label_ok
+        n_labels = getattr(engine, "n_labels", None)
 
         def admit(xs, ys, taus, active, qmask, racc):
             ok_x = jnp.all(jnp.isfinite(xs), axis=-1)
-            if classification:
-                ok_y = (ys >= 0) & (ys < n_labels)
-            else:
-                ok_y = jnp.isfinite(ys)
+            ok_y = label_ok(ys, n_labels)
             ok_tau = jnp.isfinite(taus) & (taus >= 0.0) & (taus <= 1.0)
             live = active & ~qmask[None, :]
             eff = live & ok_x & ok_y & ok_tau
@@ -106,20 +102,8 @@ class TickGuard:
             ]).astype(jnp.int32)
             return eff, racc + counts
 
-        def poison_cls(state):
-            bad_x = jnp.any(~jnp.isfinite(state.knn.X), axis=(1, 2))
-            bad_b = jnp.any(jnp.isnan(state.knn.best), axis=(1, 2))
-            return bad_x | bad_b
-
-        def poison_reg(state):
-            bad_x = jnp.any(~jnp.isfinite(state.X), axis=(1, 2))
-            bad_y = jnp.any(~jnp.isfinite(state.y), axis=1)
-            bad_d = jnp.any(jnp.isnan(state.nbr_d), axis=(1, 2))
-            return bad_x | bad_y | bad_d
-
         self._admit = jax.jit(admit)
-        self._poison = jax.jit(poison_cls if classification
-                               else poison_reg)
+        self._poison = jax.jit(engine.measure.poison)
         self._qmask = jnp.zeros((S,), dtype=bool)
         self.quarantined: set = set()
         self._racc = jnp.zeros((len(REJECT_KINDS),), dtype=jnp.int32)
@@ -147,7 +131,7 @@ class TickGuard:
         xs = jnp.asarray(xs)
         ys = jnp.asarray(ys)
         taus = jnp.asarray(taus)
-        ydt = jnp.int32 if self._classification else eng.dtype
+        ydt = eng.y_dtype
         if active is None:  # cached all-ones mask: no per-chunk alloc
             if self._ones is None or self._ones.shape != ys.shape:
                 self._ones = jnp.ones(ys.shape, dtype=bool)
@@ -233,20 +217,16 @@ class TickGuard:
         snap_cap = int(lane_state.D.shape[-1])
         cur_cap = int(state.D.shape[-1])
         if snap_cap != cur_cap:
-            if eng._wmax is not None or snap_cap > cur_cap:
+            if eng.window is not None or snap_cap > cur_cap:
                 return state
-            from repro.serving.fleet import repad_cls, repad_reg
-            repad = repad_cls if self._classification else repad_reg
-            lane_state = repad(lane_state, cur_cap)
+            lane_state = eng.measure.repad(lane_state, cur_cap)
         if any(np.issubdtype(np.asarray(l).dtype, np.floating)
                and not np.all(np.isfinite(np.asarray(l)))
                for l in jax.tree_util.tree_leaves(lane_state)):
             return state  # the snapshot itself is poisoned: stay frozen
-        state = jax.tree_util.tree_map(
+        state = eng.adopt(jax.tree_util.tree_map(
             lambda L, v: L.at[lane].set(v.astype(L.dtype)), state,
-            lane_state)
-        state = eng._shard_state(state)
-        eng.reset_occupancy()
+            lane_state))
         self.quarantined.discard(lane)
         self._restores += 1
         if self.metrics is not None:

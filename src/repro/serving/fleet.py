@@ -44,12 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.regression import stream as reg_stream
-from repro.regression.engine import RegressionServingEngine
-from repro.regression.stream import RegStreamState
-from repro.serving import session as cls_sess_m
-from repro.serving.engine import ServingEngine
-from repro.serving.session import Session
+from repro.serving.families import ENGINES, MEASURES
 
 
 def pow2_buckets(cap_min: int, cap_max: int) -> list[int]:
@@ -60,49 +55,6 @@ def pow2_buckets(cap_min: int, cap_max: int) -> list[int]:
     while bounds[-1] < cap_max:
         bounds.append(min(bounds[-1] * 2, int(cap_max)))
     return bounds
-
-
-def repad_cls(sess: Session, new_cap: int) -> Session:
-    """``serving.session.grow`` to an arbitrary target capacity."""
-    from repro.core.online import BIG, OnlineKnnState
-
-    extra = new_cap - sess.capacity
-    sess = cls_sess_m.to_linear(sess)
-    knn = sess.knn
-    return Session(
-        knn=OnlineKnnState(
-            X=jnp.pad(knn.X, ((0, extra), (0, 0))),
-            y=jnp.pad(knn.y, (0, extra), constant_values=-1),
-            best=jnp.pad(knn.best, ((0, extra), (0, 0)),
-                         constant_values=BIG),
-            n=knn.n,
-        ),
-        D=jnp.pad(sess.D, ((0, extra), (0, extra)), constant_values=BIG),
-        head=sess.head,
-        aid=jnp.pad(sess.aid, (0, extra)),
-        wrap=jnp.int32(new_cap),
-    )
-
-
-def repad_reg(state: RegStreamState, new_cap: int) -> RegStreamState:
-    """``regression.session.grow`` to an arbitrary target capacity."""
-    from repro.core.regression import BIG
-
-    extra = new_cap - state.capacity
-    state = reg_stream.to_linear(state)
-    return RegStreamState(
-        X=jnp.pad(state.X, ((0, extra), (0, 0))),
-        y=jnp.pad(state.y, (0, extra)),
-        D=jnp.pad(state.D, ((0, extra), (0, extra)), constant_values=BIG),
-        nbr_d=jnp.pad(state.nbr_d, ((0, extra), (0, 0)),
-                      constant_values=BIG),
-        nbr_y=jnp.pad(state.nbr_y, ((0, extra), (0, 0))),
-        n=state.n,
-        head=state.head,
-        aid=jnp.pad(state.aid, (0, extra)),
-        wrap=jnp.int32(new_cap),
-        nbr_a=jnp.pad(state.nbr_a, ((0, extra), (0, 0))),
-    )
 
 
 class _Pool:
@@ -120,11 +72,9 @@ class _Pool:
     def set_lane(self, lane: int, lane_state) -> None:
         """Scatter one session tree into the stacked state (host-side
         rare path: O(S·cap²) copy, like the engines' own ``grow``)."""
-        self.state = jax.tree_util.tree_map(
+        self.state = self.engine.adopt(jax.tree_util.tree_map(
             lambda L, v: L.at[lane].set(v.astype(L.dtype)), self.state,
-            lane_state)
-        self.state = self.engine._shard_state(self.state)
-        self.engine.reset_occupancy()
+            lane_state))
 
     def get_lane(self, lane: int):
         return jax.tree_util.tree_map(lambda L: L[lane], self.state)
@@ -166,7 +116,7 @@ class Fleet:
                  cost_ratio: float = 2.0, pool_sessions: int = 64,
                  dtype=jnp.float32, shards: int = 1, metrics=None,
                  guard: bool = False):
-        if mode not in ("classification", "regression"):
+        if mode not in MEASURES:
             raise ValueError(f"unknown fleet mode {mode!r}")
         if cap_min < k:
             raise ValueError(f"cap_min {cap_min} < k {k}")
@@ -174,6 +124,7 @@ class Fleet:
         self.k = k
         self.n_labels = n_labels
         self.mode = mode
+        self._measure = MEASURES[mode]
         self.dtype = dtype
         self.shards = shards
         self.pool_sessions = -(-pool_sessions // shards) * shards
@@ -193,18 +144,16 @@ class Fleet:
     # -- engine/pool plumbing -----------------------------------------------
 
     def _make_engine(self, capacity: int):
-        kw = dict(n_sessions=self.pool_sessions, capacity=capacity,
-                  dim=self.dim, k=self.k, window=None, dtype=self.dtype,
-                  shards=self.shards)
-        if self.mode == "classification":
-            return ServingEngine(n_labels=self.n_labels, **kw)
-        return RegressionServingEngine(**kw)
+        return ENGINES[self.mode].build(
+            n_sessions=self.pool_sessions, capacity=capacity, dim=self.dim,
+            k=self.k, n_labels=self.n_labels, window=None, dtype=self.dtype,
+            shards=self.shards)
 
     def _init_lane(self, capacity: int):
         lane = self._init_lane_cache.get(capacity)
         if lane is None:
-            m = cls_sess_m if self.mode == "classification" else reg_stream
-            lane = m.init(capacity, self.dim, self.k, dtype=self.dtype)
+            lane = self._measure.init(capacity, self.dim, self.k,
+                                      dtype=self.dtype)
             self._init_lane_cache[capacity] = lane
         return lane
 
@@ -270,9 +219,7 @@ class Fleet:
         if new_cap <= src_cap:
             return
         src_pool = self._pools[src_cap][spi]
-        repad = (repad_cls if self.mode == "classification"
-                 else repad_reg)
-        lane_state = repad(src_pool.get_lane(slane), new_cap)
+        lane_state = self._measure.repad(src_pool.get_lane(slane), new_cap)
         del src_pool.lane_tenant[slane]
         src_pool.set_lane(slane, self._init_lane(src_pool.engine.capacity))
         src_pool.free.append(slane)
@@ -299,17 +246,16 @@ class Fleet:
         import numpy as np
 
         if self.guard:
+            m = self._measure
+            ydt = m.y_dtype or self.dtype
             live = {}
             out_rej: dict[Any, jnp.ndarray] = {}
             for tid, (x, y, tau) in items.items():
                 ok = bool(np.all(np.isfinite(
                     np.asarray(x, dtype=np.float64))))
                 yf = float(np.asarray(y).astype(np.float64))
-                if self.mode == "classification":
-                    ok = (ok and np.isfinite(yf)
-                          and 0 <= int(yf) < self.n_labels)
-                else:
-                    ok = ok and bool(np.isfinite(yf))
+                ok = ok and bool(np.isfinite(yf)) and bool(m.label_ok(
+                    np.asarray(yf).astype(ydt), self.n_labels, np))
                 tau_f = float(tau)
                 ok = ok and bool(np.isfinite(tau_f)) and 0.0 <= tau_f <= 1.0
                 if ok:
@@ -318,11 +264,8 @@ class Fleet:
                     self._counter("fleet_rejected_observes_total")
                     out_rej[tid] = jnp.asarray(float("nan"),
                                                dtype=self.dtype)
-            if out_rej:
-                items = live
-                out_rej.update(self._observe_live(items))
-                return out_rej
-            items = live
+            out_rej.update(self._observe_live(live))
+            return out_rej
         return self._observe_live(items)
 
     def _observe_live(self, items: dict[Any, tuple]) -> dict[Any, jnp.ndarray]:
@@ -341,9 +284,8 @@ class Fleet:
         for (cap, pi), lanes in sorted(groups.items()):
             pool = self._pools[cap][pi]
             S = pool.engine.n_sessions
-            ydt = np.int32 if self.mode == "classification" else self.dtype
             xs = np.zeros((S, self.dim), dtype=self.dtype)
-            ys = np.zeros((S,), dtype=ydt)
+            ys = np.zeros((S,), dtype=pool.engine.y_dtype)
             taus = np.zeros((S,), dtype=self.dtype)
             act = np.zeros((S,), dtype=bool)
             for lane, (tid, x, y, tau) in lanes.items():
@@ -407,9 +349,4 @@ class Fleet:
                 "pools": pools}
 
 
-# historic private names (pre-robustness); the guard's lane-restore and
-# external callers use the public ones
-_repad_cls = repad_cls
-_repad_reg = repad_reg
-
-__all__ = ["Fleet", "pow2_buckets", "repad_cls", "repad_reg"]
+__all__ = ["Fleet", "pow2_buckets"]

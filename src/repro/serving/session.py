@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import online
+from repro.core.engine import Measure
 from repro.core.online import (BIG, OnlineKnnState, cshift,
                                next_aid as _next_aid, ring_live,
                                ring_mod as _mod, ring_slots)
@@ -412,16 +413,17 @@ def to_linear(sess: Session) -> Session:
                    jnp.zeros((), jnp.int32), aid, jnp.int32(cap))
 
 
-def grow(sess: Session, factor: int = 2) -> Session:
-    """Double (by default) capacity host-side, preserving all live state.
+def repad(sess: Session, capacity: int) -> Session:
+    """The session at a larger ``capacity``, host-side, all live state
+    kept.
 
-    Shapes change, so jitted steps retrace — but only O(log n) times over
-    a session's lifetime, the capacity-doubling schedule. The ring is
-    normalized to linear order first (ring positions are modulus-bound,
-    so they cannot survive a capacity change). Not jittable.
+    Shapes change, so jitted steps retrace — but only O(log n) times
+    over a session's lifetime under the capacity-doubling schedule. The
+    ring is normalized to linear order first (ring positions are
+    modulus-bound, so they cannot survive a capacity change); every leaf
+    is then padded with its inert fill. Not jittable.
     """
-    cap = sess.capacity
-    extra = cap * (factor - 1)
+    extra = capacity - sess.capacity
     sess = to_linear(sess)
     knn = sess.knn
     return Session(
@@ -435,8 +437,14 @@ def grow(sess: Session, factor: int = 2) -> Session:
         D=jnp.pad(sess.D, ((0, extra), (0, extra)), constant_values=BIG),
         head=sess.head,
         aid=jnp.pad(sess.aid, (0, extra)),
-        wrap=jnp.int32(cap * factor),
+        wrap=jnp.int32(capacity),
     )
+
+
+def grow(sess: Session, factor: int = 2) -> Session:
+    """Double (by default) capacity host-side: ``repad`` to
+    ``factor`` times the capacity."""
+    return repad(sess, sess.capacity * factor)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_labels"))
@@ -487,7 +495,24 @@ def predict_pvalues(sess: Session, X_test, *, k, n_labels):
     return (counts + 1.0) / (knn.n + 1.0)
 
 
+def poison_cls(state: Session):
+    """The lanes of a stacked state whose features or neighbour
+    distances hold a non-finite value (NaN for the BIG-padded lists)."""
+    bad_x = jnp.any(~jnp.isfinite(state.knn.X), axis=(1, 2))
+    bad_b = jnp.any(jnp.isnan(state.knn.best), axis=(1, 2))
+    return bad_x | bad_b
+
+
+#: the classification binding of ``core.engine.Engine``
+MEASURE = Measure(
+    name="classification", init=init, step=_sliding_step,
+    step_compact=_sliding_step_compact, repad=repad,
+    n_of=lambda s: s.knn.n, X_of=lambda s: s.knn.X, y_dtype=jnp.int32,
+    label_ok=lambda ys, n_labels, xp=jnp: (ys >= 0) & (ys < n_labels),
+    poison=poison_cls)
+
+
 __all__ = ["Session", "init", "observe", "observe_donated", "evict_oldest",
            "evict_oldest_donated", "observe_sliding",
-           "observe_sliding_donated", "grow", "predict_pvalues",
-           "to_linear"]
+           "observe_sliding_donated", "grow", "repad", "predict_pvalues",
+           "to_linear", "poison_cls", "MEASURE"]

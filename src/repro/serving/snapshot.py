@@ -24,35 +24,35 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.store import CheckpointStore
-from repro.core.online import OnlineKnnState
-from repro.regression.engine import RegressionServingEngine
-from repro.regression.stream import RegStreamState
-from repro.serving.engine import ServingEngine
-from repro.serving.session import Session
+from repro.serving.families import ENGINES, MEASURES
+
+
+def _state_of(leaves):
+    """The serving state whose flattened leaves ``leaves`` are: 8 for
+    a classification ``Session`` (X, y, best, n, D, head, aid, wrap), 10
+    for a regression ``RegStreamState`` (X, y, D, nbr_d, nbr_y, n, head,
+    aid, wrap, nbr_a)."""
+    for m in MEASURES.values():
+        if m.treedef.num_leaves == len(leaves):
+            return m.treedef.unflatten(leaves)
+    raise ValueError(
+        f"snapshot has {len(leaves)} leaves; expected 8 (classification "
+        "Session), 10 (regression RegStreamState), or the legacy 5/6 "
+        "linear forms — not a serving snapshot?")
 
 
 def _like_from_manifest(manifest: dict):
     """Zero-filled restore target (possibly batched) matching the leaves.
 
-    8 leaves = classification ``Session`` (X, y, best, n, D, head, aid,
-    wrap); 10 leaves = regression ``RegStreamState`` (X, y, D, nbr_d,
-    nbr_y, n, head, aid, wrap, nbr_a). Pre-ring snapshots carried 5 / 6
-    leaves (no ring bookkeeping); they restore into a plain leaf list
-    that ``_from_legacy`` upgrades to a linear-layout ring state.
+    Pre-ring snapshots carried 5 / 6 leaves (no ring bookkeeping); they
+    restore into a plain leaf list that ``_from_legacy`` upgrades to a
+    linear-layout ring state.
     """
     specs = manifest["leaves"]
     zeros = [jnp.zeros(tuple(s["shape"]), dtype=s["dtype"]) for s in specs]
     if len(specs) in (5, 6):
         return zeros  # legacy linear snapshot: synthesized below
-    if len(specs) == 8:
-        X, y, best, n, D, head, aid, wrap = zeros
-        return Session(OnlineKnnState(X, y, best, n), D, head, aid, wrap)
-    if len(specs) == 10:
-        return RegStreamState(*zeros)
-    raise ValueError(
-        f"snapshot has {len(specs)} leaves; expected 8 (classification "
-        "Session), 10 (regression RegStreamState), or the legacy 5/6 "
-        "linear forms — not a serving snapshot?")
+    return _state_of(zeros)
 
 
 def _from_legacy(leaves):
@@ -76,7 +76,7 @@ def _from_legacy(leaves):
     aid = jnp.where(live, pos, 0).astype(jnp.int32)
     wrap = jnp.full_like(n, cap)
     if len(leaves) == 5:
-        return Session(OnlineKnnState(X, y, best, n), D, head, aid, wrap)
+        return _state_of([X, y, best, n, D, head, aid, wrap])
 
     k = nbr_d.shape[-1]
 
@@ -88,35 +88,7 @@ def _from_legacy(leaves):
     for _ in range(D.ndim - 2):
         fn = jax.vmap(fn)
     nbr_a = fn(D)
-    return RegStreamState(X, y, D, nbr_d, nbr_y, n, head, aid, wrap,
-                          nbr_a)
-
-
-def _fit_ring_modulus(engine, state):
-    """Align a restored state's ring modulus with the target engine.
-
-    A pre-ring (legacy) snapshot restores with a full-capacity modulus;
-    a sliding engine runs its ring inside the ``[:window]`` block. The
-    two agree whenever the state is unwrapped (head == 0) and fits the
-    window — then the modulus can simply be re-pinned. Anything else is
-    a real mismatch and is left for ``check_window_occupancy`` to
-    reject with its diagnostic.
-    """
-    if engine._wmax is None:
-        return state
-    wrap = jnp.asarray(state.wrap)
-    if (int(jnp.max(wrap)) == engine._wmax
-            and int(jnp.min(wrap)) == engine._wmax):
-        return state
-    n = state.n if isinstance(state, RegStreamState) else state.knn.n
-    if int(jnp.max(state.head)) != 0 or int(jnp.max(n)) > engine._wmax:
-        return state  # genuinely incompatible; the engine check reports
-    new_wrap = jnp.full_like(wrap, engine._wmax)
-    if isinstance(state, RegStreamState):
-        return RegStreamState(state.X, state.y, state.D, state.nbr_d,
-                              state.nbr_y, state.n, state.head, state.aid,
-                              new_wrap, state.nbr_a)
-    return Session(state.knn, state.D, state.head, state.aid, new_wrap)
+    return _state_of([X, y, D, nbr_d, nbr_y, n, head, aid, wrap, nbr_a])
 
 
 class SessionStore:
@@ -149,7 +121,7 @@ class SessionStore:
             self._tracer.record(op, wall, tenants=tenants)
         return out
 
-    def save(self, step: int, state: Session, *, meta: dict | None = None,
+    def save(self, step: int, state, *, meta: dict | None = None,
              blocking: bool = False) -> None:
         """Snapshot ``state``; ``meta`` (e.g. ``engine.meta()``) rides in
         the manifest. Async by default — call ``wait()`` before exit."""
@@ -170,7 +142,7 @@ class SessionStore:
         self._store.discard(step)
 
     def restore(self, step: int | None = None
-                ) -> tuple[Session, int, dict[str, Any]]:
+                ) -> tuple[Any, int, dict[str, Any]]:
         """Load (state, step, meta) — target shapes come from the manifest.
 
         Without an explicit ``step``, a corrupted latest snapshot falls
@@ -195,11 +167,11 @@ class SessionStore:
     def restore_engine(self, step: int | None = None):
         """Rebuild the engine *and* its state from the latest snapshot.
 
-        Returns ``(engine, state, step)`` — a ``ServingEngine`` for
-        classification snapshots, a ``RegressionServingEngine`` when the
-        saved meta says ``mode == "regression"``. Geometry (n_sessions,
-        capacity, dim) is taken from the saved arrays; k / n_labels /
-        window / dtype from the saved meta.
+        Returns ``(engine, state, step)``: the engine of the saved
+        state's family (``serving.families``), which must agree with the
+        saved meta's ``mode`` (classification where a snapshot predates
+        the key). Geometry (n_sessions, capacity, dim) is taken from the
+        saved arrays; k / n_labels / window / dtype from the saved meta.
         """
         state, step, meta = self.restore(step)
         if "k" not in meta:
@@ -207,28 +179,22 @@ class SessionStore:
                 f"snapshot step {step} carries no engine meta (saved "
                 "without meta=engine.meta()?) — use restore() and "
                 "construct the ServingEngine yourself")
-        regression = isinstance(state, RegStreamState)
-        if regression != (meta.get("mode") == "regression"):
+        treedef = jax.tree_util.tree_structure(state)
+        m = next(m for m in MEASURES.values() if m.treedef == treedef)
+        if meta.get("mode", "classification") != m.name:
             raise ValueError(
                 f"snapshot step {step}: state/meta mode mismatch "
                 f"({type(state).__name__} vs meta mode "
                 f"{meta.get('mode')!r})")
-        X = state.X if regression else state.knn.X
         meta = {
             **meta,
+            "mode": m.name,
             "n_sessions": int(state.D.shape[0]),
             "capacity": int(state.D.shape[-1]),
-            "dim": int(X.shape[-1]),
+            "dim": int(m.X_of(state).shape[-1]),
         }
-        if regression:
-            engine = RegressionServingEngine.from_meta(meta)
-        else:
-            engine = ServingEngine.from_meta(meta)
-        state = _fit_ring_modulus(engine, state)
-        # a sharded engine serves sharded state: lay the restored leaves
-        # out across the tenant mesh (no-op for shards == 1)
-        state = engine._shard_state(state)
-        return engine, state, step
+        engine = ENGINES[m.name].from_meta(meta)
+        return engine, engine.adopt(state), step
 
 
 class AsyncShardedSaver:

@@ -49,13 +49,12 @@ STAT_KEYS = ("ticks", "evictions", "ring_wraps", "occupancy_sum",
 _MAX_MASK_IDX = tuple(STAT_KEYS.index(k) for k in _MAX_KEYS)
 
 
-def make_chunk_stats_fn(n_of: Callable, head_of: Callable,
-                        wrap_of: Callable):
+def make_chunk_stats_fn(n_of: Callable):
     """Build the in-graph chunk-level stats function for one engine.
 
-    ``n_of``/``head_of``/``wrap_of`` read the per-session occupancy,
-    ring head, and ring modulus arrays from the *stacked* engine state
-    (e.g. ``lambda s: s.knn.n`` / ``lambda s: s.n``). The returned
+    ``n_of`` reads the per-session occupancy array from the *stacked*
+    engine state (e.g. ``lambda s: s.knn.n`` / ``lambda s: s.n``); the
+    ring head and modulus are its ``head`` and ``wrap``. The returned
     ``stats_fn(state, windows, actives)`` evaluates on the pre-chunk
     state and the chunk's (T, S) active mask and returns a
     (len(STAT_KEYS),) int32 vector in ``STAT_KEYS`` order — the exact
@@ -66,8 +65,8 @@ def make_chunk_stats_fn(n_of: Callable, head_of: Callable,
     def stats_fn(state, windows, actives) -> jnp.ndarray:
         i32 = jnp.int32
         n0 = n_of(state)
-        head0 = head_of(state)
-        wrap = wrap_of(state)
+        head0 = state.head
+        wrap = state.wrap
         w = windows
         act = actives.astype(i32)                       # (T, S)
         c = jnp.cumsum(act, axis=0)                     # arrivals <= t

@@ -71,18 +71,16 @@ class EngineTelemetry:
     """Instrumentation state attached to one serving engine."""
 
     def __init__(self, *, engine: str, n_of: Callable | None = None,
-                 head_of: Callable | None = None,
-                 wrap_of: Callable | None = None,
                  metrics: MetricsRegistry | None = None,
                  tracer: Tracer | None = None, sync: bool = False):
         self.engine = engine
         self.metrics = metrics if metrics is not None else get_registry()
         self.tracer = tracer
         self.sync = sync
-        # device tick stats need the state accessors; host-only callers
+        # device tick stats need the occupancy accessor; host-only callers
         # (e.g. the registry serving loop) skip them and get timing only
         if n_of is not None:
-            self.stats_fn = make_chunk_stats_fn(n_of, head_of, wrap_of)
+            self.stats_fn = make_chunk_stats_fn(n_of)
             self.ticks = TickStats(self.metrics, engine=engine)
         else:
             self.stats_fn = None

@@ -93,28 +93,23 @@ class ReplayResult:
 
 def _make_engine(kind: str, *, tenants, capacity, window, dim, k,
                  n_labels, metrics, tracer):
-    if kind == "regression":
-        from repro.regression import RegressionServingEngine
-        return RegressionServingEngine(
-            n_sessions=tenants, capacity=capacity, dim=dim, k=k,
-            window=window, instrument=True, metrics=metrics,
-            tracer=tracer, sync_timing=True)
-    from repro.serving import ServingEngine
-    return ServingEngine(
+    from repro.serving.families import ENGINES
+    return ENGINES[kind].build(
         n_sessions=tenants, capacity=capacity, dim=dim, k=k,
         n_labels=n_labels, window=window, instrument=True,
         metrics=metrics, tracer=tracer, sync_timing=True)
 
 
 def _tick_traffic(seed: int, seq: int, tick: int, S: int, dim: int,
-                  kind: str):
-    """One tick of deterministic synthetic traffic for record ``seq``."""
+                  y_dtype):
+    """One tick of deterministic synthetic traffic for record ``seq``:
+    binary labels for an integer label dtype, Gaussian ones else."""
     rng = np.random.default_rng((seed, seq, tick))
     x = rng.standard_normal((S, dim)).astype(np.float32)
-    if kind == "regression":
-        y = rng.standard_normal(S).astype(np.float32)
-    else:
+    if np.issubdtype(y_dtype, np.integer):
         y = (rng.random(S) < 0.5).astype(np.int32)
+    else:
+        y = rng.standard_normal(S).astype(np.float32)
     tau = rng.random(S).astype(np.float32)
     return x, y, tau
 
@@ -234,11 +229,11 @@ def replay(records: Iterable[dict[str, Any]], *,
         for wi, T in enumerate(tick_counts):
             xs, ys, taus = _stack_ticks(
                 [(10 ** 9 + wi, j) for j in range(T)], seed, S, dim,
-                engine)
+                eng.y_dtype)
             warm_state, _ = drivers[si].observe_many(
                 warm_state, xs[:, lo:hi], ys[:, lo:hi], taus[:, lo:hi])
         if warm_reads:
-            _read(eng, warm_state, engine, seed, 10 ** 9, dim, eps)
+            _read(eng, warm_state, seed, 10 ** 9, dim, eps)
         del warm_state
         eng.reset_occupancy()
         if eng.telemetry is not None:  # keep warmup out of the tick stats
@@ -283,7 +278,7 @@ def replay(records: Iterable[dict[str, Any]], *,
         nonlocal ticks_total, steps_total
         keys = [(played[i]["seq"], j) for i in batch
                 for j in range(played[i].get("ticks", 1))]
-        xs, ys, taus = _stack_ticks(keys, seed, S, dim, engine)
+        xs, ys, taus = _stack_ticks(keys, seed, S, dim, engs[0].y_dtype)
         _corrupt_batch(xs, ys, taus, [played[i] for i in batch],
                        engine, n_labels)
         active = _stack_active(
@@ -355,8 +350,7 @@ def replay(records: Iterable[dict[str, Any]], *,
             _flush_pending()  # a served read sees all prior writes
             d0 = time.perf_counter()
             for si, eng in enumerate(engs):
-                _read(eng, states[si], engine, seed, recs[0]["seq"], dim,
-                      eps)
+                _read(eng, states[si], seed, recs[0]["seq"], dim, eps)
             done = time.perf_counter() - t0
             _account(batch, done, time.perf_counter() - d0)
     _flush_pending()
@@ -380,13 +374,12 @@ def replay(records: Iterable[dict[str, Any]], *,
             metrics.merge(sm)
 
     # ---- report ------------------------------------------------------------
-    engine_label = ("regression" if engine == "regression"
-                    else "classification")
     per_op: dict[str, dict[str, float]] = {}
     for op in sorted({r["op"] for r in played}):
-        eng_op = _engine_op(op, engine)
+        # the engine op a trace op lands on (reads are remapped)
+        eng_op = "observe_many" if op in _DRIVE_OPS else engs[0].read_op
         h = metrics.histogram(f"engine_{eng_op}_wall_s",
-                              engine=engine_label)
+                              engine=engs[0].measure.name)
         s = metrics.histogram("replay_sojourn_s", op=op).snapshot()
         per_op[op] = {
             "p50_s": h.quantile(0.5), "p99_s": h.quantile(0.99),
@@ -451,14 +444,7 @@ def replay(records: Iterable[dict[str, Any]], *,
     return ReplayResult(report, state, eng_out, metrics)
 
 
-def _engine_op(trace_op: str, engine: str) -> str:
-    """The engine op a trace op lands on (reads are remapped)."""
-    if trace_op in _DRIVE_OPS:
-        return "observe_many"
-    return "intervals" if engine == "regression" else "predict"
-
-
-def _corrupt_batch(xs, ys, taus, recs: list[dict[str, Any]], kind: str,
+def _corrupt_batch(xs, ys, taus, recs: list[dict[str, Any]], mode: str,
                    n_labels: int) -> None:
     """Apply each record's stamped traffic value fault (schema v3
     ``fault`` field) to its rows of the stacked tick arrays, in place."""
@@ -466,7 +452,6 @@ def _corrupt_batch(xs, ys, taus, recs: list[dict[str, Any]], kind: str,
         return
     from repro.robustness.faults import VALUE_FAULTS, poisoned_values
 
-    mode = "regression" if kind == "regression" else "classification"
     off = 0
     for r in recs:
         T = r.get("ticks", 1)
@@ -486,8 +471,8 @@ def _corrupt_batch(xs, ys, taus, recs: list[dict[str, Any]], kind: str,
 
 
 def _stack_ticks(keys: list[tuple[int, int]], seed: int, S: int, dim: int,
-                 kind: str):
-    cols = [_tick_traffic(seed, sq, j, S, dim, kind) for sq, j in keys]
+                 y_dtype):
+    cols = [_tick_traffic(seed, sq, j, S, dim, y_dtype) for sq, j in keys]
     xs = np.stack([c[0] for c in cols])
     ys = np.stack([c[1] for c in cols])
     taus = np.stack([c[2] for c in cols])
@@ -507,13 +492,11 @@ def _stack_active(recs: list[dict[str, Any]], S: int) -> np.ndarray:
     return np.stack(rows)
 
 
-def _read(eng, state, kind: str, seed: int, seq: int, dim: int,
-          eps: float, m: int = 4):
+def _read(eng, state, seed: int, seq: int, dim: int, eps: float,
+          m: int = 4):
     rng = np.random.default_rng((seed, seq))
     xq = rng.standard_normal((m, dim)).astype(np.float32)
-    if kind == "regression":
-        return eng.intervals(state, xq, eps)
-    return eng.predict(state, xq)
+    return eng.read(state, xq, eps)
 
 
 def calibrate_engine(engine: str = "classification", *, tenants: int = 8,
@@ -542,7 +525,7 @@ def calibrate_engine(engine: str = "classification", *, tenants: int = 8,
         for r in range(reps + 1):  # +1: the compile rep, flagged
             xs, ys, taus = _stack_ticks(
                 [(ci * (reps + 1) + r, j) for j in range(T)],
-                seed, tenants, dim, engine)
+                seed, tenants, dim, eng.y_dtype)
             state, _ = eng.observe_many(state, xs, ys, taus)
     tr.close()
     return [_json.loads(line) for line in buf.getvalue().splitlines()]

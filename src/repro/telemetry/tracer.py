@@ -56,7 +56,7 @@ optional, schema v3 (fault schedule — robustness.faults)
     counters on a flush record) and must be JSON-serializable.
 
 The engines write their own ``repro.<op>`` spans into a profile being
-captured (``core.engine_utils.DispatchSpans``), so a record's op lines up
+captured (``core.engine.DispatchSpans``), so a record's op lines up
 with a device trace taken through ``jax.profiler.trace()`` by name.
 """
 from __future__ import annotations

@@ -566,3 +566,98 @@ def test_registry_custom_measure_plugs_in():
             ConformalPredictor("_test_mean_dist", bogus=1)
     finally:
         registry._REGISTRY.pop("_test_mean_dist", None)
+
+
+# ---------------------------------------------------------------------------
+# one engine over a measure binding: what every family's engine keeps
+# ---------------------------------------------------------------------------
+
+FAMILIES = ["classification", "regression"]
+#: the module name of each family's read program
+READ_MODULE = {"classification": "jit_predict_pvalues",
+               "regression": "jit__lambda"}
+
+
+def _family_engine(family, mode, window=8, **kw):
+    from repro.serving.families import ENGINES
+
+    return ENGINES[family](n_sessions=2, capacity=16, dim=3, k=3,
+                           window=window if mode == "sliding" else None,
+                           **kw)
+
+
+def _drive(eng, state, T, seed=0):
+    """``T`` ticks of 0/1 labels (either family takes them) in one chunk."""
+    rng = np.random.default_rng(seed)
+    S = eng.n_sessions
+    xs = jnp.asarray(rng.standard_normal((T, S, eng.dim)), jnp.float32)
+    ys = jnp.asarray(rng.integers(0, 2, (T, S)))
+    taus = jnp.asarray(rng.random((T, S)), jnp.float32)
+    return eng.observe_many(state, xs, ys, taus)[0]
+
+
+@pytest.mark.parametrize("mode", ["sliding", "grow"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_engine_programs_keep_their_module_names(family, mode):
+    eng = _family_engine(family, mode)
+    assert eng.lower_tick(2).as_text().startswith("module @jit_chunk")
+    assert eng.lower_read(2).as_text().startswith(
+        f"module @{READ_MODULE[family]}")
+
+
+@pytest.mark.parametrize("mode", ["sliding", "grow"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_lowered_tick_carries_stage_scopes(family, mode):
+    import re
+
+    text = _family_engine(family, mode).lower_tick(2).as_text(
+        debug_info=True)
+    # grow mode drops the eviction machinery statically where it can
+    scopes = (("evict", "learn", "write") if mode == "sliding"
+              else ("learn", "write"))
+    for scope in scopes:
+        assert re.search(rf"/{scope}/|vmap\({scope}\)", text), scope
+
+
+@pytest.mark.parametrize("mode", ["sliding", "grow"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_meta_restores_same_family_engine_bitwise(family, mode):
+    from repro.serving.families import ENGINES
+
+    eng = _family_engine(family, mode, donate=False)
+    state = _drive(eng, eng.init_state(), 20)  # wraps, or grows past 16
+    again = type(eng).from_meta(eng.meta())
+    assert again.meta() == eng.meta()
+    assert eng.meta()["mode"] == family
+    assert (jax.tree_util.tree_structure(state)
+            == ENGINES[family].measure.treedef)
+    with tempfile.TemporaryDirectory() as d:
+        SessionStore(d).save(20, state, meta=eng.meta(), blocking=True)
+        eng2, state2, step = SessionStore(d).restore_engine()
+    assert step == 20 and type(eng2) is type(eng)
+    assert eng2.measure.name == family and eng2.meta() == eng.meta()
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(state2)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", ["over-full", "window-modulus",
+                                  "grow-modulus"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_engine_rejects_state_outside_its_invariants(family, case):
+    if case == "over-full":  # a grow engine's 12 points into window 8
+        src = _family_engine(family, "grow")
+        eng, match = _family_engine(family, "sliding"), "exceeds"
+        T = 12
+    elif case == "window-modulus":  # a window-6 ring into window 8
+        src = _family_engine(family, "sliding", window=6)
+        eng, match = _family_engine(family, "sliding"), "ring modulus"
+        T = 4
+    else:  # a window-8 ring into a grow engine
+        src = _family_engine(family, "sliding")
+        eng, match = _family_engine(family, "grow"), "ring modulus"
+        T = 4
+    state = _drive(src, src.init_state(), T)
+    with pytest.raises(ValueError, match=match):
+        _drive(eng, state, 1)
