@@ -115,10 +115,11 @@ def hull_sweep(lo, hi, empty, thresh):
     deltas = jnp.concatenate([jnp.where(empty, 0.0, 1.0),
                               jnp.where(empty, 0.0, -1.0)])
     # order ties so that +1 events at a point apply before -1 events leave:
-    # sort by (point, -delta) -> stable count at closed endpoints
-    order = jnp.lexsort((-deltas, pts))
-    pts_s = pts[order]
-    runs = jnp.cumsum(deltas[order])
+    # sort by (point, -delta) -> stable count at closed endpoints. The
+    # values are sorted themselves (no permutation to gather through):
+    # the same stable sort ``lexsort`` runs, so the same bits come out.
+    pts_s, neg_s = jax.lax.sort((pts, -deltas), num_keys=2)
+    runs = jnp.cumsum(-neg_s)
     ok = runs > thresh
     any_ok = jnp.any(ok & jnp.isfinite(pts_s))
     lo_out = jnp.min(jnp.where(ok, pts_s, INF))

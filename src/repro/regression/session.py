@@ -16,12 +16,14 @@ engine needs, all fixed-shape and vmappable:
 * ``intervals`` / ``pvalues`` — capacity-padded read paths, computed on
   the ``arrival_view`` (an O(cap) gather into arrival order, so the
   historic linear-layout expressions — and their bits — are unchanged,
-  equal-distance tie order included). ``intervals`` routes the fused
-  distance-row + (a_i, b_i) update + critical-point computation through
-  ``kernels.ops.interval_sweep`` (the Pallas kernel on TPU) and
-  finishes with the shared ``regression.hull_sweep``; padded rows
-  contribute neutral events, so results are bit-identical to
-  ``regression.intervals_optimized`` on the live window (property-tested;
+  equal-distance tie order included). Where the Pallas kernels are live,
+  ``intervals`` instead reads the ring in slot order and routes the
+  fused distance-row + (a_i, b_i) update + critical-point computation
+  through ``kernels.ops.interval_sweep``; both routes finish with the
+  shared ``regression.hull_sweep``, which depends only on the set of
+  events. Padded rows contribute neutral events, so results are
+  bit-identical to ``regression.intervals_optimized`` on the live window
+  on the XLA route (property-tested;
   the one caveat is an ``epsilon`` sitting exactly on the p == epsilon
   rank boundary, where f32 vs f64 threshold rounding may legitimately
   differ — the same measure-zero tie the batch tests dodge with
@@ -42,7 +44,8 @@ from repro.core.regression import BIG, _interval_ge, hull_sweep
 from repro.kernels import ops as kops
 from repro.regression import stream
 from repro.regression.stream import RegStreamState, _mod_cap, _next_aid
-from repro.core.online import cshift, drop_backfill, ring_live, slot_set
+from repro.core.online import (cshift, drop_backfill, ring_age, ring_live,
+                               slot_set)
 
 init = stream.init
 
@@ -387,38 +390,52 @@ def intervals(state: RegStreamState, X_test, *, k, epsilon):
 
     ``epsilon`` is traced (one compile serves every level — it only feeds
     the sweep threshold, and a traced f32 rounds identically to the
-    embedded constant). The state is read through its ``arrival_view``
-    (O(cap) gather; ``D`` untouched), after which the computation is the
-    historic linear one. Where the Pallas kernels are live (TPU, or
-    interpret mode), the
-    distance row + (a_i, b_i) update + critical points come fused from
-    ``kops.interval_sweep``. Elsewhere the computation structurally
-    mirrors ``regression.intervals_optimized`` (per-test ``lax.map``,
-    vmapped ``_interval_ge``), so XLA emits the very same fused
-    arithmetic and the results are bit-identical to the batch optimized
-    path on the live window — the fully-batched form differs by ~1 ulp
-    in the endpoints through different FMA contraction.
+    embedded constant). Where the Pallas kernels are live (TPU, or
+    interpret mode), the distance row + (a_i, b_i) update + critical
+    points come fused from ``kops.interval_sweep``, and the ring is read
+    in slot order (``D`` untouched, nothing gathered): the sweep is per
+    column and the hull depends only on the set of events, so only the
+    candidate's ``a`` needs arrival order, for ``top_k``'s tie-break
+    toward the older point and its nearest-first sum; one sort over
+    (distance, age) carrying the labels gives both. Elsewhere the state
+    is read through its ``arrival_view`` (O(cap) gather) and the
+    computation structurally mirrors ``regression.intervals_optimized``
+    (per-test ``lax.map``, vmapped ``_interval_ge``), so XLA emits the
+    very same fused arithmetic and the results are bit-identical to the
+    batch optimized path on the live window — the fully-batched form
+    differs by ~1 ulp in the endpoints through different FMA contraction.
     """
-    with jax.named_scope("gather"):
-        Xg, yg, a_prime, upd, kth, kth_label, live = _arrival_stats(
-            state, k=k)
     thresh = epsilon * (state.n + 1.0) - 1.0
 
     if kops.pallas_active(state.X.dtype):
+        with jax.named_scope("gather"):
+            age = ring_age(state.capacity, state.head, state.wrap)
+            live = age < state.n
+            a_prime, _ = stream.slot_stats(state, k=k)
+            y = jnp.where(live, state.y, 0.0)
         with jax.named_scope("query"):
-            d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, Xg), 0.0))
+            d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, state.X), 0.0))
             dm = jnp.where(live[None, :], d, BIG)
-            _, idx = jax.lax.top_k(-dm, k)
-            a_test = -jnp.sum(yg[idx], axis=1) / k
-        with jax.named_scope("sweep"):
+            # live ages are distinct; dead slots (BIG, label 0) tie freely
+            _, _, y_near = jax.lax.sort(
+                (stream._total_order_key(dm),
+                 jnp.broadcast_to(age, dm.shape),
+                 jnp.broadcast_to(y, dm.shape)),
+                num_keys=2, is_stable=False)
+            a_test = -jnp.sum(y_near[:, :k], axis=1) / k
+        with jax.named_scope("sweep"):  # dead columns come out empty
             lo, hi = kops.interval_sweep(
-                Xg, a_prime, kth, kth_label, live, X_test, a_test, k)
+                state.X, a_prime, state.nbr_d[:, -1], state.nbr_y[:, -1],
+                live, X_test, a_test, k)
 
         def sweep(lo_r, hi_r):
             return jnp.stack(hull_sweep(lo_r, hi_r, lo_r > hi_r, thresh))
 
         with jax.named_scope("hull"):
             return jax.vmap(sweep)(lo, hi)
+
+    with jax.named_scope("gather"):
+        Xg, yg, a_prime, upd, kth, _, live = _arrival_stats(state, k=k)
 
     def per_test(x_t):
         with jax.named_scope("query"):

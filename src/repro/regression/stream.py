@@ -202,27 +202,35 @@ def to_linear(state: RegStreamState) -> RegStreamState:
                           view.nbr_a)
 
 
+def slot_stats(state: RegStreamState, *, k):
+    """Per-slot ``(a_prime, upd)`` in slot order, on the raw leaves —
+    the exact expressions of the historic linear path and of ``fit``.
+
+    The optimization barrier after the arithmetic pins the fusion
+    boundary: XLA compiles the reduce+divide+subtract chain in its own
+    small computation (the shape in which its accumulation order
+    matches ``fit``'s — a big consumer graph can re-vectorize the
+    reduce and round odd lanes 1 ulp apart). This is what keeps the
+    served reads bit-identical to the batch path regardless of the
+    surrounding graph (session jit or the engine's mapped jit).
+    """
+    a_prime = state.y - jnp.sum(state.nbr_y, axis=1) / k
+    upd = a_prime + state.nbr_y[:, -1] / k
+    return jax.lax.optimization_barrier((a_prime, upd))
+
+
 def arrival_stats(state: RegStreamState, *, k):
     """Arrival-ordered (X, y, a_prime, upd, kth, kth_label, live) — the
-    one shared gather behind every regression read path.
+    one shared gather behind the regression read paths that must match
+    the batch path's row order.
 
-    The per-row derived statistics are computed *in slot space* on the
-    raw leaves — the exact expressions of the historic linear path and
-    of ``fit`` — and only then gathered into arrival order. The
-    optimization barrier between the arithmetic and the gather pins the
-    fusion boundary: XLA compiles the reduce+divide+subtract chain in
-    its own small computation (the shape in which its accumulation
-    order matches ``fit``'s — a big consumer graph can re-vectorize the
-    reduce and round odd lanes 1 ulp apart), and the gathers after the
-    barrier are bit-preserving moves. This is what keeps the served
-    reads bit-identical to the batch path regardless of the surrounding
-    graph (session jit or the engine's mapped jit). Rows beyond ``n``
-    carry the linear layout's inert fills.
+    The per-row derived statistics come from ``slot_stats`` and only
+    then are gathered into arrival order; the gathers after its barrier
+    are bit-preserving moves. Rows beyond ``n`` carry the linear
+    layout's inert fills.
     """
     cap = state.capacity
-    a_prime_s = state.y - jnp.sum(state.nbr_y, axis=1) / k
-    upd_s = a_prime_s + state.nbr_y[:, -1] / k
-    a_prime_s, upd_s = jax.lax.optimization_barrier((a_prime_s, upd_s))
+    a_prime_s, upd_s = slot_stats(state, k=k)
     slots = ring_slots(cap, state.head, state.wrap)
     live = jnp.arange(cap) < state.n
     X = jnp.where(live[:, None], state.X[slots], 0.0)
@@ -494,7 +502,7 @@ def from_fit(X, y, *, k, capacity: int) -> RegStreamState:
                    capacity=int(capacity))
 
 
-__all__ = ["RegStreamState", "init", "state_view", "arrival_stats",
-           "observe",
-           "observe_donated", "evict", "evict_donated", "evict_oldest",
-           "evict_oldest_donated", "from_fit", "arrival_view", "to_linear"]
+__all__ = ["RegStreamState", "init", "state_view", "slot_stats",
+           "arrival_stats", "observe", "observe_donated", "evict",
+           "evict_donated", "evict_oldest", "evict_oldest_donated",
+           "from_fit", "arrival_view", "to_linear"]

@@ -96,3 +96,90 @@ def test_pvalue_at_boundary_cases():
     d = np.abs(p_std - p_opt)
     assert (d > 1e-6).mean() <= 0.02, d.max()
     assert d.max() <= 2.5 / (X.shape[0] + 1), d.max()
+
+
+# ---------------------------------------------------------------------------
+# the hull sweep sorts its events as values == the lexsort-and-gather form
+# ---------------------------------------------------------------------------
+
+
+def _hull_sweep_gather(lo, hi, empty, thresh):
+    """``hull_sweep`` as a permutation from ``lexsort`` and gathers
+    through it (the reference form)."""
+    INF = jnp.inf
+    pts = jnp.concatenate([jnp.where(empty, INF, lo),
+                           jnp.where(empty, INF, hi)])
+    deltas = jnp.concatenate([jnp.where(empty, 0.0, 1.0),
+                              jnp.where(empty, 0.0, -1.0)])
+    order = jnp.lexsort((-deltas, pts))
+    pts_s = pts[order]
+    runs = jnp.cumsum(deltas[order])
+    ok = runs > thresh
+    any_ok = jnp.any(ok & jnp.isfinite(pts_s))
+    lo_out = jnp.min(jnp.where(ok, pts_s, INF))
+    nxt = jnp.concatenate([pts_s[1:], jnp.array([INF])])
+    hi_out = jnp.max(jnp.where(ok, nxt, -INF))
+    return jnp.where(any_ok, lo_out, jnp.nan), jnp.where(any_ok, hi_out,
+                                                         jnp.nan)
+
+
+_HULL_CAP, _HULL_ROWS = 24, 40
+
+
+def _hull_events(case, rng):
+    """(R, cap) interval ends and empty masks; rows mix the case's
+    feature with random ones."""
+    R, cap = _HULL_ROWS, _HULL_CAP
+    a = rng.normal(size=(R, cap)).astype(np.float32)
+    b = rng.normal(size=(R, cap)).astype(np.float32)
+    if case == "duplicated":  # few distinct endpoints, lo == hi often
+        a = np.round(a * 2) / 2
+        b = np.where(rng.rand(R, cap) < 0.3, a, np.round(b * 2) / 2)
+    elif case == "signed-zeros":  # +0.0 beside -0.0 at both ends
+        z = rng.choice(np.float32([-0.0, 0.0]), size=(2, R, cap))
+        pick = rng.rand(2, R, cap) < 0.5
+        a, b = np.where(pick[0], z[0], a), np.where(pick[1], z[1], b)
+    elif case == "inf-ends":
+        a = np.where(rng.rand(R, cap) < 0.3, -np.inf, a)
+        b = np.where(rng.rand(R, cap) < 0.3, np.inf, b)
+        b = np.where(rng.rand(R, cap) < 0.05, -np.inf, b)
+    lo, hi = np.minimum(a, b), np.maximum(a, b)
+    # some intervals empty as given (lo > hi), some masked empty
+    swap = rng.rand(R, cap) < 0.1
+    lo, hi = np.where(swap, hi, lo), np.where(swap, lo, hi)
+    empty = (lo > hi) | (rng.rand(R, cap) < 0.1)
+    empty[:3] = True  # empty rows
+    empty[3, 1:] = True  # a single live event pair
+    return (jnp.asarray(lo, jnp.float32), jnp.asarray(hi, jnp.float32),
+            jnp.asarray(empty))
+
+
+# thresholds below every count, at the counts' ends and past them
+_HULL_THRESH = (-np.inf, -1.0, -0.5, 0.0, 0.5, 1.0, _HULL_CAP - 1.0,
+                float(_HULL_CAP), np.inf)
+
+
+@pytest.mark.parametrize("form", ["single", "vmap"])
+@pytest.mark.parametrize("case", ["random", "duplicated", "signed-zeros",
+                                  "inf-ends"])
+def test_hull_sweep_sorts_like_lexsort_gather(case, form):
+    """The hull from one stable sort over the event values is
+    bit-identical to the lexsort permutation and its gathers, on every
+    row and threshold: empty rows (NaN), ties at an endpoint, +0.0
+    beside -0.0, infinite ends."""
+    import jax
+
+    rng = np.random.RandomState(["random", "duplicated", "signed-zeros",
+                                 "inf-ends"].index(case))
+    lo, hi, empty = _hull_events(case, rng)
+    for thresh in map(jnp.float32, _HULL_THRESH):
+        fns = [jax.jit(f) for f in (reg.hull_sweep, _hull_sweep_gather)]
+        if form == "vmap":  # the read path's (rows, 2 cap) batch
+            new, ref = (jnp.stack(jax.vmap(f, (0, 0, 0, None))(
+                lo, hi, empty, thresh)) for f in fns)
+        else:
+            new, ref = (jnp.stack([jnp.stack(f(lo[r], hi[r], empty[r],
+                                               thresh))
+                                   for r in range(8)]) for f in fns)
+        assert np.asarray(new).tobytes() == np.asarray(ref).tobytes(), \
+            (case, float(thresh))

@@ -593,6 +593,103 @@ def test_backfill_pick_matches_arrival_rank_gather(case):
 
 
 # ---------------------------------------------------------------------------
+# the interval read in slot order == the read gathered into arrival order
+# ---------------------------------------------------------------------------
+
+
+def _intervals_arrival(state, X_test, *, k, epsilon):
+    """The kernels' interval read on the ring gathered into arrival
+    order through ``arrival_stats``, ``top_k`` and a gather of its
+    labels (the reference form)."""
+    from repro.kernels import ops as kops
+
+    Xg, yg, a_prime, _, kth, kth_label, live = rstream.arrival_stats(
+        state, k=k)
+    thresh = epsilon * (state.n + 1.0) - 1.0
+    d = jnp.sqrt(jnp.maximum(kops.sq_dists(X_test, Xg), 0.0))
+    dm = jnp.where(live[None, :], d, BIG)
+    _, idx = jax.lax.top_k(-dm, k)
+    a_test = -jnp.sum(yg[idx], axis=1) / k
+    lo, hi = kops.interval_sweep(Xg, a_prime, kth, kth_label, live, X_test,
+                                 a_test, k)
+    return jax.vmap(lambda l, h: jnp.stack(
+        reg.hull_sweep(l, h, l > h, thresh)))(lo, hi)
+
+
+# (capacity, window, ticks, grow then ticks, data)
+_READ_CASES = {
+    "wrapped": (16, 16, 40, 0, "continuous"),
+    "confined": (24, 16, 40, 0, "continuous"),
+    "repadded": (16, 12, 30, 9, "continuous"),
+    "tied-kth": (16, 16, 40, 0, "grid"),
+}
+
+
+@pytest.mark.parametrize("case", list(_READ_CASES))
+def test_kernel_intervals_slot_order_equal_arrival_order(case, monkeypatch):
+    """The served interval read on the kernels' route (interpret mode)
+    reads the ring in slot order; it gives the same intervals as the
+    same read on the ring gathered into arrival order: wrapped rings, a
+    ring confined to a window block, a grown state whose modulus is
+    below its capacity, lanes inactive on some ticks (one nearly
+    always), and duplicated feature rows with other labels tied at the
+    k-th neighbour. Compared as values: the order of the events may
+    swap the sign of an exact zero end."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    jax.clear_caches()
+    cap, w, T, T2, data = _READ_CASES[case]
+    S, k, eps = 3, 4, 0.157
+    rng = np.random.RandomState(cap + w + T)
+    if data == "grid":
+        X = jnp.asarray(rng.randint(0, 2, size=(T + T2 + 6, DIM)),
+                        jnp.float32)
+        y = jnp.asarray(rng.choice([-0.0, 0.0, 1.0, 2.5], size=T + T2 + 6),
+                        jnp.float32)
+    else:
+        X, y, _ = _reg_stream(T + T2 + 6, seed=cap + T)
+    eng = RegressionServingEngine(n_sessions=S, capacity=cap, dim=DIM,
+                                  k=k, window=w, donate=False)
+    state = eng.init_state()
+    for lo, hi in ((0, T), (T, T + T2)):
+        if hi == lo:
+            continue
+        if lo:
+            state = eng.grow(state)
+        ts = jnp.arange(lo, hi)
+        # lane s takes tick t from the stream shifted by s; lane 2 is
+        # active on one tick in three
+        xs = jnp.stack([jnp.roll(X, -s, axis=0)[lo:hi] for s in range(S)],
+                       axis=1)
+        ys = jnp.stack([jnp.roll(y, -s)[lo:hi] for s in range(S)], axis=1)
+        active = jnp.stack([ts % 5 != 1, ts % 7 != 3, ts % 3 == 0], axis=1)
+        state, _ = eng.observe_many(state, xs, ys,
+                                    jnp.full((hi - lo, S), 0.5), active)
+    Xt = X[T + T2:]
+    assert int(jnp.min(state.n)) >= k
+    assert int(jnp.max(state.head)) > 0  # a ring wrapped
+    if case == "repadded":
+        assert state.capacity == 2 * cap and int(state.wrap[0]) == w
+    got = np.asarray(eng.intervals(state, Xt, epsilon=eps))
+    want = np.asarray(jax.jit(lambda st: jax.lax.map(
+        lambda s: _intervals_arrival(s, Xt, k=k, epsilon=jnp.float32(eps)),
+        st))(state))
+    jax.clear_caches()
+    assert np.isfinite(got).any()
+    np.testing.assert_array_equal(got, want)
+    if data == "grid":  # a tie across the k-th neighbour, between labels
+        view = jax.vmap(lambda s: rstream.arrival_stats(s, k=k))(state)
+        Xg, yg = np.asarray(view[0]), np.asarray(view[1])
+        tied = False
+        for s, n in enumerate(np.asarray(state.n)):
+            for q in np.asarray(Xt):
+                dq = np.sum((Xg[s, :n] - q) ** 2, axis=1)
+                at = dq == np.sort(dq)[k - 1]
+                tied |= bool(np.sum(dq <= np.sort(dq)[k - 1]) > k
+                             and len(set(yg[s, :n][at])) > 1)
+        assert tied
+
+
+# ---------------------------------------------------------------------------
 # the O(cap) eviction claim, on the optimized HLO (via the auditor —
 # repro.analysis.audit owns the single definition of this invariant)
 # ---------------------------------------------------------------------------

@@ -187,6 +187,20 @@ def test_engine_chunk_keeps_distance_block_row_major(one_chip, tpu_route,
     assert "dist_insert" in text
 
 
+def _gathers(text: str) -> list[tuple[str, str]]:
+    """(result shape, slice sizes) of every gather in a compiled text."""
+    return re.findall(r"= [a-z]+\d+\[([\d,]*)\]\S* gather\(.*?"
+                      r"slice_sizes=\{([\d,]*)\}", text)
+
+
+def _per_element(gathers, sizes: set[int]) -> list[tuple[str, str]]:
+    """The gathers with slice sizes all 1 (a result of one element per
+    index) whose result has one of ``sizes`` elements."""
+    return [(shape, sl) for shape, sl in gathers
+            if set(sl.split(",")) == {"1"}
+            and np.prod([int(d) for d in shape.split(",") if d]) in sizes]
+
+
 def test_regression_tick_gathers_no_slot_vector(one_chip, tpu_route):
     """The regression tick never permutes a (window,) vector into arrival
     order: the compiled chunk at 256 tenants x window 512 holds no gather
@@ -196,13 +210,39 @@ def test_regression_tick_gathers_no_slot_vector(one_chip, tpu_route):
     a scalar or a whole row per tenant remain."""
     cap = 512
     compiled, _ = _compile_chunk(one_chip, "regression", cap)
-    gathers = re.findall(r"= [a-z]+\d+\[([\d,]*)\]\S* gather\(.*?"
-                         r"slice_sizes=\{([\d,]*)\}", compiled.as_text())
+    gathers = _gathers(compiled.as_text())
     assert gathers  # the pattern still reads the compiled text
-    per_element = [
-        (shape, sizes) for shape, sizes in gathers
-        if set(sizes.split(",")) == {"1"}
-        and np.prod([int(d) for d in shape.split(",") if d]) == TENANTS * cap]
+    per_element = _per_element(gathers, {TENANTS * cap})
+    assert not per_element, per_element
+
+
+@pytest.mark.parametrize("rows", [4, 32])
+def test_regression_read_gathers_no_event_vector(one_chip, tpu_route, rows):
+    """The served interval read at 256 tenants x window 512 never
+    permutes a vector element by element: no gather with unit slices
+    whose result has one element per hull event (rows x 2 cap) or per
+    slot (cap). The hull sorts its events as values, and the read stays
+    in slot order. The pattern is first shown to find the arrival-order
+    gathers that the read did without."""
+    from repro.regression import RegressionServingEngine
+    from repro.regression import stream
+
+    cap = 512
+    eng = RegressionServingEngine(n_sessions=TENANTS, capacity=cap,
+                                  dim=DIM, k=K, window=cap)
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(eng.init_state))
+    sizes = {rows * 2 * cap, cap}
+    arrival = jax.jit(lambda st: jax.lax.map(
+        lambda s: stream.arrival_stats(s, k=K), st))
+    assert _per_element(_gathers(
+        arrival.lower(state).compile().as_text()), sizes)
+    text = eng._reads["intervals"].lower(
+        state, _spec(one_chip, (TENANTS, rows, DIM)),
+        _spec(one_chip, ())).compile().as_text()
+    assert "interval_sweep" in text
+    per_element = _per_element(_gathers(text), sizes)
     assert not per_element, per_element
 
 
